@@ -12,10 +12,6 @@ import argparse
 import sys
 import time
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-
 # suites import lazily so one missing optional dep (e.g. bench_ratio's
 # zstandard baseline) cannot take down the others
 SUITES = {
@@ -39,6 +35,8 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated suite names")
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     only = set(args.only.split(",")) if args.only else None
 
     print("name,us_per_call,derived")

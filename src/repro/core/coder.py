@@ -150,6 +150,17 @@ def encode_records(symbols: jax.Array, tbl: TableSet,
     return compact_records(bytes_rec, mask_rec, s, cap)
 
 
+def _vary_like(tree, like: jax.Array):
+    """Mark a fresh scan carry as varying over the mesh axes ``like`` varies
+    over, so that inside ``jax.shard_map`` its type matches what the scan
+    body returns (a no-op outside a mesh)."""
+    axes = tuple(jax.typeof(like).vma)
+    if not axes:
+        return tree
+    return jax.tree.map(lambda a: jax.lax.pcast(a, axes, to="varying"),
+                        tree)
+
+
 @functools.partial(jax.jit, static_argnames=("cap",))
 def encode(symbols: jax.Array, tbl: TableSet,
            cap: int | None = None) -> EncodedLanes:
@@ -172,7 +183,8 @@ def encode(symbols: jax.Array, tbl: TableSet,
         return encode_put(st, xs, tbl), None
 
     xs = (symbols.T, tbl) if per_position else symbols.T  # scan over T
-    st, _ = jax.lax.scan(step, encoder_init(lanes, cap), xs, reverse=True)
+    st0 = _vary_like(encoder_init(lanes, cap), symbols)
+    st, _ = jax.lax.scan(step, st0, xs, reverse=True)
     st = encoder_flush(st)
     # a cursor past the buffer head means the stream did not fit `cap`:
     # the writes were dropped (never wrapped), length reports the need.

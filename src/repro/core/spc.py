@@ -241,12 +241,23 @@ def decode_lut(tables: TableSet, prob_bits: int = C.PROB_BITS) -> jax.Array:
 # numpy convenience (host-side table prep, container tooling)
 # ---------------------------------------------------------------------------
 
+def _host_device():
+    """The CPU device for host-side table prep, or None (the default
+    device) where the CPU backend is not initialised.  Either gives the
+    same tables: the quantizer is a bf16 cast, a power-of-two scale, a
+    round and integer bookkeeping, all exact on every backend."""
+    try:
+        return jax.devices("cpu")[0]
+    except RuntimeError:
+        return None
+
+
 def tables_from_counts_np(counts: np.ndarray,
                           prob_bits: int = C.PROB_BITS) -> TableSet:
     """Host-side helper: raw symbol counts -> TableSet (adds +1 smoothing)."""
     counts = np.asarray(counts, np.float64)
     probs = (counts + 1.0) / (counts + 1.0).sum(-1, keepdims=True)
-    with jax.default_device(jax.devices("cpu")[0]):
+    with jax.default_device(_host_device()):
         return jax.tree.map(np.asarray,
                             tables_from_probs(jnp.asarray(probs, jnp.float32),
                                               prob_bits))

@@ -257,7 +257,7 @@ def Uniform(bits: int, prob_bits: int = C.PROB_BITS) -> Codec:
 
 def Categorical(freq: jax.Array, cdf: jax.Array,
                 prob_bits: int = C.PROB_BITS,
-                backend: str = "coder", interpret: bool = True) -> Codec:
+                backend: str = "coder") -> Codec:
     """Codec over quantized ``(freq, cdf)`` planes (``spc.quantize_probs``
     / ``spc.freq_cdf_from_probs`` output), shared ``(K,)`` or per-lane
     ``(lanes, K)``.
@@ -285,8 +285,7 @@ def Categorical(freq: jax.Array, cdf: jax.Array,
 
         def pop(st: StackState):
             s, ptr, x, _, u = rans_decode_step(
-                st.buf.T, st.s, st.ptr, freq, cdf, prob_bits=prob_bits,
-                interpret=interpret)
+                st.buf.T, st.s, st.ptr, freq, cdf, prob_bits=prob_bits)
             under = st.underflow | (u > 0)
             return StackState(s, st.buf, ptr, under), x
 
@@ -302,11 +301,10 @@ def Categorical(freq: jax.Array, cdf: jax.Array,
 
 
 def from_tableset(tbl: spc.TableSet, prob_bits: int = C.PROB_BITS,
-                  backend: str = "coder", interpret: bool = True) -> Codec:
+                  backend: str = "coder") -> Codec:
     """Codec over a full :class:`repro.core.spc.TableSet` — the batch
     coder's table object, reused as a stack codec."""
-    return Categorical(tbl.freq, tbl.cdf, prob_bits, backend=backend,
-                       interpret=interpret)
+    return Categorical(tbl.freq, tbl.cdf, prob_bits, backend=backend)
 
 
 def serial(codecs) -> Codec:
@@ -397,8 +395,7 @@ def push_symbols(st: StackState, x: jax.Array, freq: jax.Array,
 
 
 def pop_symbols(st: StackState, n: int, freq: jax.Array, cdf: jax.Array,
-                prob_bits: int = C.PROB_BITS, backend: str = "coder",
-                interpret: bool = True):
+                prob_bits: int = C.PROB_BITS, backend: str = "coder"):
     """Pop ``n`` symbols per lane; returns ``(state, symbols (lanes, n))``.
 
     Table layouts as in :func:`push_symbols`.  ``backend="kernel"`` scans
@@ -420,8 +417,7 @@ def pop_symbols(st: StackState, n: int, freq: jax.Array, cdf: jax.Array,
         if backend == "kernel":
             from repro.kernels.rans_decode import rans_decode_step
             s, ptr, x, _, u = rans_decode_step(
-                buf_t, s, ptr, f_t, c_t, prob_bits=prob_bits,
-                interpret=interpret)
+                buf_t, s, ptr, f_t, c_t, prob_bits=prob_bits)
             return (s, ptr, under | (u > 0)), x
         sub = StackState(s, buf, ptr, under)
         slot = stack_slot(sub, prob_bits)
@@ -465,7 +461,7 @@ def gaussian_bin_probs(mu: jax.Array, sigma: jax.Array,
 
 def DiagGaussian(mu: jax.Array, sigma: jax.Array, edges: jax.Array,
                  prob_bits: int = C.PROB_BITS,
-                 backend: str = "coder", interpret: bool = True) -> Codec:
+                 backend: str = "coder") -> Codec:
     """Diagonal-Gaussian codec over fixed bin edges: the bits-back
     *posterior* codec (pop a latent bin index against ``q(z|x)``, push it
     back against the same ``q`` on decode).  ``mu``/``sigma`` are per-lane
@@ -473,8 +469,7 @@ def DiagGaussian(mu: jax.Array, sigma: jax.Array, edges: jax.Array,
     the BF16 storage + quantization path of :mod:`repro.core.spc`."""
     probs = gaussian_bin_probs(mu, sigma, edges)
     freq, cdf = spc.freq_cdf_from_probs(spc.store_bf16(probs), prob_bits)
-    return Categorical(freq, cdf, prob_bits, backend=backend,
-                       interpret=interpret)
+    return Categorical(freq, cdf, prob_bits, backend=backend)
 
 
 def logistic_bin_probs(mu: jax.Array, log_s: jax.Array,
@@ -494,12 +489,10 @@ def logistic_bin_probs(mu: jax.Array, log_s: jax.Array,
 
 def DiscretizedLogistic(mu: jax.Array, log_s: jax.Array, n_bins: int,
                         prob_bits: int = C.PROB_BITS,
-                        backend: str = "coder",
-                        interpret: bool = True) -> Codec:
+                        backend: str = "coder") -> Codec:
     """Discretized-logistic observation codec over ``n_bins`` pixel levels
     in normalized ``[-1, 1]`` units — the ``p(x|z)`` codec of the
     bits-back VAE."""
     probs = logistic_bin_probs(mu, log_s, n_bins)
     freq, cdf = spc.freq_cdf_from_probs(spc.store_bf16(probs), prob_bits)
-    return Categorical(freq, cdf, prob_bits, backend=backend,
-                       interpret=interpret)
+    return Categorical(freq, cdf, prob_bits, backend=backend)

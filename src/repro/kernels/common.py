@@ -20,12 +20,39 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
 
 from repro.core import constants as C
 # single-source integer primitives (core/update.py uses numpy-scalar masks,
 # so Pallas kernels see literals, not captured device constants); kept as a
 # re-export for the kernels' historical import path.
 from repro.core.update import umulhi32  # noqa: F401
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in the interpreter: compiled on a TPU,
+    interpreted on every other backend (the CPU test substrate).
+
+    The one place the choice is made, so no kernel can run interpreted on
+    the chip.  Kernels read it at trace time.
+    """
+    return jax.default_backend() != "tpu"
+
+
+# Row-blocked kernel planes are laid out (blocks, rows, ...) and per-chunk
+# vectors (n_chunks, 1, lanes), so every block's last two dims either tile
+# the TPU's (8, 128) vreg or span the whole dim, as the compiler requires;
+# the leading block axis is squeezed away and the kernels see 2-D refs.
+SQUEEZED = pl.Squeezed()
+
+
+def chunk_row_spec(lane_block: int) -> pl.BlockSpec:
+    """One chunk's ``(1, lane_block)`` row of an ``(n_chunks, 1, lanes)``
+    per-chunk plane (states, cursors, stream geometry, probe counters) on
+    the kernels' ``(lane block, chunk, T block)`` grid; trailing index-map
+    args are scalar-prefetch refs."""
+    return pl.BlockSpec((SQUEEZED, 1, lane_block),
+                        lambda i, c, j, *_: (c, 0, i))
 
 
 def pad_chunk_rows(a: jax.Array, t_len: int, chunk_size: int,
@@ -61,18 +88,29 @@ def unpad_chunk_rows(a: jax.Array, t_len: int, chunk_size: int,
     return a[jnp.asarray(rows, jnp.int32)]
 
 
+def onehot_sum(vals: jax.Array, axis: int) -> jax.Array:
+    """Sum a one-hot-masked plane along ``axis`` in ``vals.dtype``.
+
+    At most one element per output is non-zero, so the sum is that element.
+    The TPU compiler has no unsigned-integer reduction, so the sum runs in
+    int32 and converts back: both conversions keep the bits of a lone
+    uint32 or uint8 value, so the result is exact.
+    """
+    return jnp.sum(vals.astype(jnp.int32), axis=axis).astype(vals.dtype)
+
+
 def onehot_gather(table: jax.Array, idx: jax.Array) -> jax.Array:
     """``table[idx]`` as a one-hot contraction.
 
     table: (K,) uint32/int32; idx: (lanes,) int32  ->  (lanes,) table dtype.
-    Exactly one mask element is hot per lane, so a uint32 sum cannot wrap.
+    Exactly one mask element is hot per lane (see :func:`onehot_sum`).
     """
     k = table.shape[-1]
     iota = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], k), 1)
     hot = iota == idx[:, None].astype(jnp.int32)
     vals = jnp.where(hot, jnp.broadcast_to(table[None, :], hot.shape),
                      jnp.zeros_like(table, shape=hot.shape))
-    return jnp.sum(vals, axis=1, dtype=table.dtype)
+    return onehot_sum(vals, axis=1)
 
 
 def onehot_gather_lanes(table: jax.Array, idx: jax.Array) -> jax.Array:
@@ -87,7 +125,7 @@ def onehot_gather_lanes(table: jax.Array, idx: jax.Array) -> jax.Array:
     iota = jax.lax.broadcasted_iota(jnp.int32, (lanes, k), 1)
     hot = iota == idx[:, None].astype(jnp.int32)
     vals = jnp.where(hot, table, jnp.zeros_like(table))
-    return jnp.sum(vals, axis=1, dtype=table.dtype)
+    return onehot_sum(vals, axis=1)
 
 
 def onehot_gather_rows(buf: jax.Array, row_idx: jax.Array) -> jax.Array:
@@ -100,7 +138,7 @@ def onehot_gather_rows(buf: jax.Array, row_idx: jax.Array) -> jax.Array:
     iota = jax.lax.broadcasted_iota(jnp.int32, (cap, lanes), 0)
     hot = iota == row_idx[None, :].astype(jnp.int32)
     vals = jnp.where(hot, buf, jnp.zeros_like(buf))
-    return jnp.sum(vals.astype(jnp.int32), axis=0).astype(buf.dtype)
+    return onehot_sum(vals, axis=0)
 
 
 def read_state_header(buf: jax.Array, ptr: jax.Array,

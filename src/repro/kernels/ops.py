@@ -20,8 +20,8 @@ in-kernel state/pointer/context reset).  ``rans_decode_step`` (re-exported
 from ``kernels.rans_decode``) is the fused serve decode's building block:
 ONE symbol pop per lane with caller-threaded coder state, traced inside
 the model scan of ``serve.compress`` (DESIGN.md §9).  ``spc_quantize``
-wraps the mass-correction kernel.  All default to ``interpret=True`` (this
-container is CPU-only; on a real TPU pass interpret=False).
+wraps the mass-correction kernel.  Every kernel compiles on a TPU and runs
+in the Pallas interpreter elsewhere (``kernels.common.interpret_mode``).
 """
 
 from __future__ import annotations
@@ -42,8 +42,9 @@ from repro.core.coder import (ChunkedLanes, EncodedLanes, _check_exhausted,
                               default_cap, num_chunks)
 from repro.core.predictors import NeighborAverage
 from repro.core.spc import TableSet, build_tables
-from repro.kernels.rans_decode import (rans_decode_lanes, rans_decode_slab,
-                                       rans_decode_step)  # noqa: F401
+from repro.kernels.rans_decode import (plan_slab_windows,  # noqa: F401
+                                       rans_decode_lanes, rans_decode_slab,
+                                       rans_decode_step)
 from repro.kernels.rans_encode import (rans_encode_lanes,  # noqa: F401
                                        rans_encode_records)
 
@@ -55,8 +56,7 @@ def rans_encode(symbols: jax.Array, tbl: TableSet,
                 prob_bits: int = C.PROB_BITS,
                 lane_block: int = 128,
                 t_block: int | None = None,
-                scatter: str = "ring",
-                interpret: bool = True) -> EncodedLanes:
+                scatter: str = "ring") -> EncodedLanes:
     """Kernel-backed multi-lane encode (bit-exact vs. core/golden).
 
     Fused datapath: ONE ``pallas_call`` returning finished wire-format
@@ -75,7 +75,7 @@ def rans_encode(symbols: jax.Array, tbl: TableSet,
         return _header_only_stream(lanes, cap)
     buf, start, length, overflow = rans_encode_lanes(
         symbols, tbl, cap=cap, prob_bits=prob_bits, lane_block=lane_block,
-        t_block=t_block, scatter=scatter, interpret=interpret)
+        t_block=t_block, scatter=scatter)
     return EncodedLanes(buf=buf[0], start=start[0], length=length[0],
                         overflow=overflow[0])
 
@@ -105,8 +105,7 @@ def rans_encode_chunked(symbols: jax.Array, tbl: TableSet, chunk_size: int,
                         prob_bits: int = C.PROB_BITS,
                         lane_block: int = 128,
                         t_block: int | None = None,
-                        scatter: str = "ring",
-                        interpret: bool = True) -> ChunkedLanes:
+                        scatter: str = "ring") -> ChunkedLanes:
     """Kernel-backed chunked encode (bit-exact vs. coder.encode_chunked).
 
     ONE ``pallas_call`` for the whole stream: the chunk axis is a grid
@@ -129,8 +128,7 @@ def rans_encode_chunked(symbols: jax.Array, tbl: TableSet, chunk_size: int,
                             overflow=jnp.zeros((0, lanes), bool))
     buf, start, length, overflow = rans_encode_lanes(
         symbols, tbl, cap=cap, chunk_size=chunk_size, prob_bits=prob_bits,
-        lane_block=lane_block, t_block=t_block, scatter=scatter,
-        interpret=interpret)
+        lane_block=lane_block, t_block=t_block, scatter=scatter)
     return ChunkedLanes(buf=buf, start=start, length=length,
                         overflow=overflow)
 
@@ -142,7 +140,6 @@ def rans_decode(enc: EncodedLanes, n_symbols: int, tbl: TableSet,
                 candidates: jax.Array | None = None,
                 lane_block: int = 128,
                 t_block: int | None = None,
-                interpret: bool = True,
                 lane_probes: bool = False,
                 exhausted_flags: bool = False):
     """Kernel-backed decode; returns (symbols (lanes,T), avg probes/symbol).
@@ -175,7 +172,7 @@ def rans_decode(enc: EncodedLanes, n_symbols: int, tbl: TableSet,
     sym, probes, under = rans_decode_lanes(
         enc.buf, enc.start, tbl.freq, tbl.cdf, t_len=n_symbols,
         prob_bits=prob_bits, predictor=predictor, candidates=candidates,
-        lane_block=lane_block, t_block=t_block, interpret=interpret)
+        lane_block=lane_block, t_block=t_block)
     probes = probes[0]
     under = under[0] > 0
     avg = jnp.mean(probes.astype(jnp.float32)) / n_symbols
@@ -195,7 +192,6 @@ def rans_decode_chunked(chunks: ChunkedLanes | None = None,
                         candidates: jax.Array | None = None,
                         lane_block: int = 128,
                         t_block: int | None = None,
-                        interpret: bool = True,
                         lane_probes: bool = False,
                         chunk_probes: bool = False,
                         exhausted_flags: bool = False,
@@ -263,27 +259,20 @@ def rans_decode_chunked(chunks: ChunkedLanes | None = None,
         # window size: >= 4 so the state-header read always has rows even
         # for degenerate (hostile but validated) all-empty indexes
         cap = max(cs.cap, 4)
-        slab = np.asarray(cs.slab, np.uint8)
-        if slab.shape[0] < cap:        # tiny payload: pad so base=0 works
-            slab = np.concatenate(
-                [slab, np.zeros(cap - slab.shape[0], np.uint8)])
-        # host-clipped DMA bases: the in-kernel copy can never leave the
-        # slab; wstart re-bases each cell's offset into its window
-        base = np.clip(cs.offset, 0, slab.shape[0] - cap).astype(np.int32)
-        wstart = (cs.offset - base).astype(np.int32)
+        words, base, wstart = plan_slab_windows(
+            np.asarray(cs.slab, np.uint8), cs.offset, cap)
         wlen = cs.length.astype(np.int32)
         sym, cprobes, cunder = rans_decode_slab(
-            jnp.asarray(slab), jnp.asarray(base), jnp.asarray(wstart),
+            jnp.asarray(words), jnp.asarray(base), jnp.asarray(wstart),
             jnp.asarray(wlen), tbl.freq, tbl.cdf, cap=cap,
             t_len=n_symbols, chunk_size=chunk_size, prob_bits=prob_bits,
             predictor=predictor, candidates=candidates,
-            lane_block=lane_block, t_block=t_block, interpret=interpret)
+            lane_block=lane_block, t_block=t_block)
     else:
         sym, cprobes, cunder = rans_decode_lanes(
             chunks.buf, chunks.start, tbl.freq, tbl.cdf, t_len=n_symbols,
             chunk_size=chunk_size, prob_bits=prob_bits, predictor=predictor,
-            candidates=candidates, lane_block=lane_block, t_block=t_block,
-            interpret=interpret)
+            candidates=candidates, lane_block=lane_block, t_block=t_block)
     avg_probes = (jnp.sum(cprobes.astype(jnp.float32))
                   / (lanes * n_symbols))
     out = (sym, avg_probes)
@@ -301,8 +290,7 @@ def rans_decode_step_rows(buf_t: jax.Array, s: jax.Array, ptr: jax.Array,
                           tbl: TableSet,
                           prob_bits: int = C.PROB_BITS,
                           candidates: jax.Array | None = None,
-                          backend: str = "kernel",
-                          interpret: bool = True):
+                          backend: str = "kernel"):
     """One rANS symbol pop across a flattened ``slots x lanes`` row axis.
 
     The batched serve engine's step primitive (``serve.engine``): rows are
@@ -326,7 +314,7 @@ def rans_decode_step_rows(buf_t: jax.Array, s: jax.Array, ptr: jax.Array,
     if backend == "kernel":
         s2, ptr2, sym, probes, under = rans_decode_step(
             buf_t, s, ptr, tbl.freq, tbl.cdf, prob_bits=prob_bits,
-            candidates=candidates, interpret=interpret)
+            candidates=candidates)
         return s2, ptr2, sym, probes, (under > 0).astype(jnp.int32)
     if backend != "coder":
         raise ValueError(f"unknown step backend {backend!r}")
@@ -339,10 +327,8 @@ def rans_decode_step_rows(buf_t: jax.Array, s: jax.Array, ptr: jax.Array,
 
 def spc_quantize_tables(probs: jax.Array,
                         prob_bits: int = C.PROB_BITS,
-                        batch_block: int = 8,
-                        interpret: bool = True) -> TableSet:
+                        batch_block: int = 8) -> TableSet:
     """Kernel-backed SPC: batched probs -> full TableSet."""
     from repro.kernels.spc_quantize import spc_quantize
-    freq = spc_quantize(probs, prob_bits=prob_bits, batch_block=batch_block,
-                        interpret=interpret)
+    freq = spc_quantize(probs, prob_bits=prob_bits, batch_block=batch_block)
     return build_tables(freq, prob_bits)

@@ -61,18 +61,46 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import constants as C
 from repro.core import search
-from repro.kernels.common import (masked_refill, onehot_gather,
-                                  onehot_gather_lanes, pad_chunk_rows,
+from repro.kernels import common
+from repro.kernels.common import (SQUEEZED, chunk_row_spec, masked_refill,
+                                  onehot_gather, onehot_gather_lanes,
+                                  onehot_gather_rows, pad_chunk_rows,
                                   read_state_header, unpad_chunk_rows)
 
 _U32 = jnp.uint32
 _I32 = jnp.int32
-_U8 = jnp.uint8
+
+
+def _decode_out_specs(tb: int, n_tb: int, lane_block: int) -> list:
+    """Symbols (one T block of rows) + per-chunk probe/underflow rows.
+    Index maps take the slab path's scalar-prefetch refs as trailing
+    args (``*_``)."""
+    return [pl.BlockSpec((SQUEEZED, tb, lane_block),
+                         lambda i, c, j, *_: (c * n_tb + j, 0, i)),
+            chunk_row_spec(lane_block),
+            chunk_row_spec(lane_block)]
+
+
+def _decode_out_shape(n_blocks: int, tb: int, n_chunks: int,
+                      lanes: int) -> list:
+    return [jax.ShapeDtypeStruct((n_blocks, tb, lanes), _I32),
+            jax.ShapeDtypeStruct((n_chunks, 1, lanes), _I32),
+            jax.ShapeDtypeStruct((n_chunks, 1, lanes), _I32)]
+
+
+def _decode_outputs(sym, probes, under, t_len, chunk, n_chunks,
+                    padded_chunk):
+    """Kernel planes -> ``(symbols (lanes, T), probes, under)`` with the
+    padding rows dropped and the per-chunk planes ``(n_chunks, lanes)``."""
+    sym = unpad_chunk_rows(sym.reshape(-1, sym.shape[-1]), t_len, chunk,
+                           n_chunks, padded_chunk)
+    return sym.T, probes[:, 0], under[:, 0]
 
 
 def _decode_kernel(*refs,
@@ -82,9 +110,10 @@ def _decode_kernel(*refs,
                    cap: int = 0):
     if slab:
         # zero-copy source (DESIGN.md §10): the packed container payload
-        # stays one (S,) slab in HBM (memory_space=ANY); per-(chunk, lane)
-        # DMA starts ride the grid as a scalar-prefetch plane and each
-        # chunk's byte windows are DMA'd into a lane-major VMEM scratch.
+        # stays one slab of little-endian u32 words in HBM (memory_space=
+        # ANY); per-(chunk, lane) DMA block starts ride the grid as a
+        # scalar-prefetch plane and each chunk's windows are DMA'd into
+        # VMEM, then turned word-major / lane-minor like the dense layout.
         base_ref, slab_ref, wstart_ref, wlen_ref, freq_ref, cdf_ref, \
             *rest = refs
     else:
@@ -93,7 +122,7 @@ def _decode_kernel(*refs,
         cand_ref, *rest = rest
     if slab:
         sym_ref, probes_ref, under_ref, s_scr, ptr_scr, ctx_scr, win_scr, \
-            sem = rest
+            words_scr, sem = rest
     else:
         sym_ref, probes_ref, under_ref, s_scr, ptr_scr, ctx_scr = rest
     lanes = sym_ref.shape[1]
@@ -101,35 +130,47 @@ def _decode_kernel(*refs,
     i = pl.program_id(0)      # lane-block index
     c = pl.program_id(1)      # chunk index
     j = pl.program_id(2)      # T-block index (innermost grid axis)
-    # per-lane byte access: dense layout is (cap, lanes) row gathers, the
-    # slab window is lane-major (lanes, cap) — same OOB-reads-0 contract
-    byte_gather = onehot_gather_lanes if slab else None
+    if slab:
+        # per-lane byte access of the slab layout: byte p of a lane's window
+        # is byte p & 3 of word p >> 2 (little-endian).  In-kernel span
+        # clamp: bytes outside the cell's validated [wstart, wstart + min(
+        # length, cap)) span read as 0 — identical to the dense path's
+        # out-of-stream reads, and a hostile index can never surface
+        # another cell's bytes (the DMA base is host-clipped into the
+        # slab, so the copy itself is in-bounds).
+        ws = wstart_ref[0].astype(_I32)
+        wl = wlen_ref[0].astype(_I32)
+        live_end = ws + jnp.minimum(wl, cap)
+
+        def byte_gather(words, p):
+            w = onehot_gather_rows(words, p >> 2)
+            b = (w >> ((p & 3) << 3).astype(_U32)) & _U32(0xFF)
+            return jnp.where((p >= ws) & (p < live_end), b, _U32(0))
+    else:
+        byte_gather = onehot_gather_rows
 
     @pl.when(j == 0)
     def _init():
         # per-chunk re-init: every chunk is a standalone stream — read its
         # 4-byte big-endian state header and reset cursors/probes/context
         if slab:
+            rows = words_scr.shape[0] // 128     # window rows of 128 words
+
             def dma(lane, _):
-                b = base_ref[c, i * lanes + lane]
-                cp = pltpu.make_async_copy(slab_ref.at[pl.ds(b, cap)],
-                                           win_scr.at[lane], sem)
+                b = pl.multiple_of(base_ref[c, i * lanes + lane] * 8, 8)
+                dst = pl.multiple_of(lane * rows, 8)
+                cp = pltpu.make_async_copy(slab_ref.at[pl.ds(b, rows)],
+                                           win_scr.at[pl.ds(dst, rows)], sem)
                 cp.start()
                 cp.wait()
                 return 0
             jax.lax.fori_loop(0, lanes, dma, 0)
-            ws = wstart_ref[0].astype(_I32)
-            wl = wlen_ref[0].astype(_I32)
-            # in-kernel span-bounds clamp: bytes outside this cell's
-            # validated [wstart, wstart+length) span read as 0 — identical
-            # to the dense path's out-of-stream reads, and a hostile index
-            # can never surface another cell's bytes (the DMA base is
-            # host-clipped to [0, S-cap], so the copy itself is in-block)
-            col = jax.lax.broadcasted_iota(_I32, (lanes, cap), 1)
-            win = win_scr[...]
-            live = (col >= ws[:, None]) & (col < (ws + wl)[:, None])
-            win_scr[...] = jnp.where(live, win, _U8(0))
-            s, ptr, und = read_state_header(win_scr[...], ws,
+            # lane-major windows -> word-major (words, lanes): row r of
+            # every lane's window is one strided (lanes, 128) tile
+            for r in range(rows):
+                words_scr[pl.ds(r * 128, 128), :] = (
+                    win_scr[pl.ds(r, lanes, stride=rows), :].T)
+            s, ptr, und = read_state_header(words_scr[...], ws,
                                             gather=byte_gather,
                                             limit=ws + wl)
         else:
@@ -144,21 +185,21 @@ def _decode_kernel(*refs,
             ctx_scr[...] = predictor.init(lanes)
 
     # this chunk's byte source, resident in VMEM across its T blocks
-    buf = win_scr[...] if slab else buf_ref[0]
+    buf = words_scr[...] if slab else buf_ref[0]
     # one-past-the-end read bound per lane: the window span end for the
     # slab layout, the (right-aligned) buffer cap for the dense layout
-    read_limit = (wstart_ref[0].astype(_I32) + wlen_ref[0].astype(_I32)
-                  if slab else buf.shape[0])
+    read_limit = ws + wl if slab else buf.shape[0]
 
     if layout == "static":
         freq_all = freq_ref[0]        # (K,)
         cdf_all = cdf_ref[0]          # (K+1,)
 
-    if predictor is not None:
-        ctx0 = (ctx_scr[...] if ctx_w
-                else jnp.zeros((lanes, 0), _I32))
-    else:
-        ctx0 = jnp.zeros((lanes, 0), _I32)
+    # only a predictor with context carries it through the loop: the TPU
+    # has no zero-width vectors, so a context-free predictor (ZeroPredictor)
+    # reads a host-side empty constant instead
+    carry_ctx = predictor is not None and ctx_w > 0
+    ctx0 = ctx_scr[...] if carry_ctx else None
+    no_ctx = np.zeros((lanes, 0), np.int32)
 
     # valid rows in this T block: the final chunk may be ragged, and padding
     # rows (up to a whole T block) decode nothing
@@ -169,8 +210,12 @@ def _decode_kernel(*refs,
     # host-side unpad), and valid rows overwrite below
     sym_ref[...] = jnp.zeros(sym_ref.shape, _I32)
 
+    # the loop carries the per-lane vectors as (1, lanes) rows: the TPU
+    # compiler cannot lay out a 1-D carry that feeds back through a one-hot
+    # gather (it aborts), and a 2-D row has one unambiguous layout
     def body(t, carry):
         s, ptr, probes, under, ctx = carry
+        s, ptr, probes, under = s[0], ptr[0], probes[0], under[0]
         slot = s & mask
         if layout == "static":
             freq_t, cdf_t, g = freq_all, cdf_all, onehot_gather
@@ -185,41 +230,38 @@ def _decode_kernel(*refs,
         cand_t = (cand_ref[pl.dslice(t, 1), :, :][0]       # (lanes, topk)
                   if has_cands else None)
         if predictor is not None:
-            pred = predictor.predict(ctx)
+            pred = predictor.predict(ctx if carry_ctx else no_ctx)
             cands = cand_t if has_cands else pred.candidates
             x, p = search.find_symbol(cdf_t, k, slot, mu=pred.mu,
                                       delta=pred.delta,
                                       candidates=cands, gather=g)
-            ctx = predictor.update(ctx, x)
+            if carry_ctx:
+                ctx = predictor.update(ctx, x)
         else:
             x, p = search.find_symbol(cdf_t, k, slot, candidates=cand_t,
                                       gather=g)
-        sym_ref[pl.dslice(t, 1), :] = x.reshape(1, lanes)
+        sym_ref[t, :] = x
         f = g(freq_t, x)
         start = g(cdf_t[..., :k], x)
         s = f * (s >> prob_bits) + slot - start
-        if slab:
-            s, ptr, u = masked_refill(buf, s, ptr, gather=byte_gather,
-                                      limit=read_limit)
-        else:
-            s, ptr, u = masked_refill(buf, s, ptr, limit=read_limit)
-        return s, ptr, probes + p, under + u, ctx
+        s, ptr, u = masked_refill(buf, s, ptr, gather=byte_gather,
+                                  limit=read_limit)
+        return s[None], ptr[None], (probes + p)[None], (under + u)[None], ctx
 
     s, ptr, probes, under, ctx = jax.lax.fori_loop(
-        0, n_t, body, (s_scr[0, :], ptr_scr[0, :], probes_ref[0, :],
-                       under_ref[0, :], ctx0))
-    s_scr[0, :] = s
-    ptr_scr[0, :] = ptr
-    probes_ref[0, :] = probes
-    under_ref[0, :] = under
-    if predictor is not None and ctx_w:
+        0, n_t, body, (s_scr[...], ptr_scr[...], probes_ref[...],
+                       under_ref[...], ctx0))
+    s_scr[...] = s
+    ptr_scr[...] = ptr
+    probes_ref[...] = probes
+    under_ref[...] = under
+    if carry_ctx:
         ctx_scr[...] = ctx
 
 
 @functools.partial(jax.jit,
                    static_argnames=("t_len", "chunk_size", "prob_bits",
-                                    "predictor", "lane_block", "t_block",
-                                    "interpret"))
+                                    "predictor", "lane_block", "t_block"))
 def rans_decode_lanes(buf: jax.Array,      # (lanes, cap) uint8 forward stream
                       start: jax.Array,    # (lanes,) int32
                       freq: jax.Array, cdf: jax.Array,
@@ -229,8 +271,7 @@ def rans_decode_lanes(buf: jax.Array,      # (lanes, cap) uint8 forward stream
                       predictor=None,
                       candidates: jax.Array | None = None,
                       lane_block: int = 128,
-                      t_block: int | None = None,
-                      interpret: bool = True):
+                      t_block: int | None = None):
     """Decode t_len symbols/lane — ONE ``pallas_call`` for the whole stream.
 
     Returns ``(symbols (lanes, T), probes (n_chunks, lanes))``: the probe
@@ -301,12 +342,14 @@ def rans_decode_lanes(buf: jax.Array,      # (lanes, cap) uint8 forward stream
                 f"per-position tables carry T={freq.shape[0]} rows but "
                 f"t_len={t_len}")
         layout = "perpos"
-        freq_in = pad_chunk_rows(freq, t_len, chunk, n_chunks, padded_chunk)
-        cdf_in = pad_chunk_rows(cdf, t_len, chunk, n_chunks, padded_chunk)
-        freq_spec = pl.BlockSpec((tb, k),
-                                 lambda i, c, j: (c * n_tb + j, 0))
-        cdf_spec = pl.BlockSpec((tb, k + 1),
-                                lambda i, c, j: (c * n_tb + j, 0))
+        freq_in = pad_chunk_rows(freq, t_len, chunk, n_chunks,
+                                 padded_chunk).reshape(-1, tb, k)
+        cdf_in = pad_chunk_rows(cdf, t_len, chunk, n_chunks,
+                                padded_chunk).reshape(-1, tb, k + 1)
+        freq_spec = pl.BlockSpec((SQUEEZED, tb, k),
+                                 lambda i, c, j: (c * n_tb + j, 0, 0))
+        cdf_spec = pl.BlockSpec((SQUEEZED, tb, k + 1),
+                                lambda i, c, j: (c * n_tb + j, 0, 0))
     elif freq.ndim == 3:
         if freq.shape[0] != t_len or freq.shape[1] != lanes:
             raise ValueError(
@@ -347,37 +390,62 @@ def rans_decode_lanes(buf: jax.Array,      # (lanes, cap) uint8 forward stream
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, cap, lane_block), lambda i, c, j: (c, 0, i)),
-            pl.BlockSpec((1, lane_block), lambda i, c, j: (c, i)),
+            chunk_row_spec(lane_block),
             freq_spec,
             cdf_spec,
         ] + extra_specs,
-        out_specs=[
-            pl.BlockSpec((tb, lane_block), lambda i, c, j: (c * n_tb + j, i)),
-            pl.BlockSpec((1, lane_block), lambda i, c, j: (c, i)),
-            pl.BlockSpec((1, lane_block), lambda i, c, j: (c, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((total_rows, lanes), _I32),
-            jax.ShapeDtypeStruct((n_chunks, lanes), _I32),
-            jax.ShapeDtypeStruct((n_chunks, lanes), _I32),
-        ],
+        out_specs=_decode_out_specs(tb, n_tb, lane_block),
+        out_shape=_decode_out_shape(total_rows // tb, tb, n_chunks, lanes),
         scratch_shapes=[
             pltpu.VMEM((1, lane_block), _U32),              # rANS states
             pltpu.VMEM((1, lane_block), _I32),              # read cursors
             pltpu.VMEM((lane_block, max(1, ctx_w)), _I32),  # predictor ctx
         ],
-        interpret=interpret,
-    )(buf3.swapaxes(1, 2), start2.astype(_I32), freq_in, cdf_in, *extra_in)
-    sym = unpad_chunk_rows(sym, t_len, chunk, n_chunks, padded_chunk)
-    return sym.T, probes, under
+        interpret=common.interpret_mode(),
+    )(buf3.swapaxes(1, 2), start2.astype(_I32)[:, None], freq_in,
+      cdf_in, *extra_in)
+    return _decode_outputs(sym, probes, under, t_len, chunk, n_chunks,
+                           padded_chunk)
+
+
+# Slab windows move in whole (8, 128) u32 tiles: the TPU's DMA engine copies
+# tile-aligned row ranges only, so the packed byte slab is viewed as rows of
+# 128 little-endian words and every window starts on a SLAB_BLOCK boundary.
+SLAB_BLOCK = 8 * 128 * 4
+
+
+def slab_window_rows(cap: int) -> int:
+    """Rows of 128 words in one lane's window: whole blocks covering any
+    ``cap``-byte span that starts anywhere inside its first block."""
+    return 8 * -(-(cap + SLAB_BLOCK - 1) // SLAB_BLOCK)
+
+
+def plan_slab_windows(slab: np.ndarray, offset: np.ndarray, cap: int):
+    """Host side of the zero-copy decode: the word view of the payload and
+    each (chunk, lane) cell's window.
+
+    Returns ``(words (rows, 128) uint32, base (n_chunks, lanes) int32,
+    wstart (n_chunks, lanes) int32)``: ``base`` is the window's first
+    block, clipped so the DMA can never leave the (zero-padded) slab, and
+    ``wstart = offset - base * SLAB_BLOCK`` re-bases each cell's span into
+    its window.
+    """
+    n_win = slab_window_rows(cap) // 8
+    n_blk = max(-(-slab.shape[0] // SLAB_BLOCK), n_win)
+    padded = np.zeros(n_blk * SLAB_BLOCK, np.uint8)
+    padded[:slab.shape[0]] = slab
+    words = padded.view("<u4").astype(np.uint32).reshape(-1, 128)
+    base = np.clip(offset // SLAB_BLOCK, 0, n_blk - n_win).astype(np.int64)
+    wstart = (offset - base * SLAB_BLOCK).astype(np.int32)
+    return words, base.astype(np.int32), wstart
 
 
 @functools.partial(jax.jit,
                    static_argnames=("cap", "t_len", "chunk_size",
                                     "prob_bits", "predictor", "lane_block",
-                                    "t_block", "interpret"))
-def rans_decode_slab(slab: jax.Array,      # (S,) uint8 packed payload slab
-                     base: jax.Array,      # (n_chunks, lanes) int32 DMA start
+                                    "t_block"))
+def rans_decode_slab(words: jax.Array,     # (rows, 128) uint32 payload words
+                     base: jax.Array,      # (n_chunks, lanes) int32 block
                      wstart: jax.Array,    # (n_chunks, lanes) int32 in-window
                      wlen: jax.Array,      # (n_chunks, lanes) int32 span len
                      freq: jax.Array, cdf: jax.Array, *,
@@ -388,22 +456,21 @@ def rans_decode_slab(slab: jax.Array,      # (S,) uint8 packed payload slab
                      predictor=None,
                      candidates: jax.Array | None = None,
                      lane_block: int = 128,
-                     t_block: int | None = None,
-                     interpret: bool = True):
+                     t_block: int | None = None):
     """Zero-copy chunked decode: ONE ``pallas_call`` straight off the
     packed container slab (DESIGN.md §10).
 
     The per-(chunk, lane) index walk that ``bitstream.unpack_chunked`` used
     to run host-side moves into the kernel: ``base`` rides the grid as a
-    scalar-prefetch plane (SMEM), the slab stays unblocked in HBM
-    (``memory_space=ANY``), and at each chunk's first grid step the kernel
-    DMAs every lane's ``cap``-byte window ``slab[base : base + cap]`` into
-    a lane-major VMEM scratch, then clamps bytes outside the cell's
-    validated ``[wstart, wstart + wlen)`` span to 0 before reading the
-    state header.  ``base`` must be host-clipped to ``[0, S - cap]`` (so
-    the DMA can never leave the slab) with ``wstart = offset - base`` —
-    :func:`repro.kernels.ops.rans_decode_chunked` derives all three planes
-    from a validated :class:`~repro.core.bitstream.ContainerSlab`.
+    scalar-prefetch plane (SMEM), the slab's word view stays unblocked in
+    HBM (``memory_space=ANY``), and at each chunk's first grid step the
+    kernel DMAs every lane's window of :func:`slab_window_rows` rows from
+    block ``base`` into VMEM, turns the windows word-major, and reads each
+    lane's bytes from ``wstart`` on, with bytes outside the cell's span
+    ``[wstart, wstart + min(wlen, cap))`` read as 0.
+    :func:`plan_slab_windows` derives ``words``/``base``/``wstart`` from a
+    validated :class:`~repro.core.bitstream.ContainerSlab` (see
+    :func:`repro.kernels.ops.rans_decode_chunked`).
 
     Symbols and probe counters are bit-identical to
     :func:`rans_decode_lanes` over the dense right-aligned form: the byte
@@ -436,12 +503,14 @@ def rans_decode_slab(slab: jax.Array,      # (S,) uint8 packed payload slab
                 f"per-position tables carry T={freq.shape[0]} rows but "
                 f"t_len={t_len}")
         layout = "perpos"
-        freq_in = pad_chunk_rows(freq, t_len, chunk, n_chunks, padded_chunk)
-        cdf_in = pad_chunk_rows(cdf, t_len, chunk, n_chunks, padded_chunk)
-        freq_spec = pl.BlockSpec((tb, k),
-                                 lambda i, c, j, *_: (c * n_tb + j, 0))
-        cdf_spec = pl.BlockSpec((tb, k + 1),
-                                lambda i, c, j, *_: (c * n_tb + j, 0))
+        freq_in = pad_chunk_rows(freq, t_len, chunk, n_chunks,
+                                 padded_chunk).reshape(-1, tb, k)
+        cdf_in = pad_chunk_rows(cdf, t_len, chunk, n_chunks,
+                                padded_chunk).reshape(-1, tb, k + 1)
+        freq_spec = pl.BlockSpec((SQUEEZED, tb, k),
+                                 lambda i, c, j, *_: (c * n_tb + j, 0, 0))
+        cdf_spec = pl.BlockSpec((SQUEEZED, tb, k + 1),
+                                lambda i, c, j, *_: (c * n_tb + j, 0, 0))
     elif freq.ndim == 3:
         if freq.shape[0] != t_len or freq.shape[1] != lanes:
             raise ValueError(
@@ -474,28 +543,25 @@ def rans_decode_slab(slab: jax.Array,      # (S,) uint8 packed payload slab
     ctx_w = (int(predictor.init(lane_block).shape[-1])
              if predictor is not None else 0)
     grid = (lanes // lane_block, n_chunks, n_tb)
+    win_rows = slab_window_rows(cap)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),           # the raw slab
-            pl.BlockSpec((1, lane_block), lambda i, c, j, *_: (c, i)),
-            pl.BlockSpec((1, lane_block), lambda i, c, j, *_: (c, i)),
+            pl.BlockSpec(memory_space=pl.ANY),              # slab words
+            chunk_row_spec(lane_block),
+            chunk_row_spec(lane_block),
             freq_spec,
             cdf_spec,
         ] + extra_specs,
-        out_specs=[
-            pl.BlockSpec((tb, lane_block),
-                         lambda i, c, j, *_: (c * n_tb + j, i)),
-            pl.BlockSpec((1, lane_block), lambda i, c, j, *_: (c, i)),
-            pl.BlockSpec((1, lane_block), lambda i, c, j, *_: (c, i)),
-        ],
+        out_specs=_decode_out_specs(tb, n_tb, lane_block),
         scratch_shapes=[
             pltpu.VMEM((1, lane_block), _U32),              # rANS states
             pltpu.VMEM((1, lane_block), _I32),              # read cursors
             pltpu.VMEM((lane_block, max(1, ctx_w)), _I32),  # predictor ctx
-            pltpu.VMEM((lane_block, cap), _U8),             # byte windows
+            pltpu.VMEM((lane_block * win_rows, 128), _U32),  # windows
+            pltpu.VMEM((win_rows * 128, lane_block), _U32),  # word-major
             pltpu.SemaphoreType.DMA,                        # window copies
         ],
     )
@@ -505,16 +571,12 @@ def rans_decode_slab(slab: jax.Array,      # (S,) uint8 packed payload slab
                           layout=layout, predictor=predictor, ctx_w=ctx_w,
                           has_cands=has_cands, slab=True, cap=cap),
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((total_rows, lanes), _I32),
-            jax.ShapeDtypeStruct((n_chunks, lanes), _I32),
-            jax.ShapeDtypeStruct((n_chunks, lanes), _I32),
-        ],
-        interpret=interpret,
-    )(base.astype(_I32), slab, wstart.astype(_I32), wlen.astype(_I32),
-      freq_in, cdf_in, *extra_in)
-    sym = unpad_chunk_rows(sym, t_len, chunk, n_chunks, padded_chunk)
-    return sym.T, probes, under
+        out_shape=_decode_out_shape(total_rows // tb, tb, n_chunks, lanes),
+        interpret=common.interpret_mode(),
+    )(base.astype(_I32), words, wstart.astype(_I32)[:, None],
+      wlen.astype(_I32)[:, None], freq_in, cdf_in, *extra_in)
+    return _decode_outputs(sym, probes, under, t_len, chunk, n_chunks,
+                           padded_chunk)
 
 
 # ---------------------------------------------------------------------------
@@ -562,8 +624,7 @@ def rans_decode_step(buf: jax.Array,    # (cap, lanes) uint8, lane-minor
                      ptr: jax.Array,    # (lanes,) int32 read cursors
                      freq: jax.Array, cdf: jax.Array,
                      prob_bits: int = C.PROB_BITS,
-                     candidates: jax.Array | None = None,
-                     interpret: bool = True):
+                     candidates: jax.Array | None = None):
     """Pop ONE symbol per lane; coder state lives with the caller.
 
     Tables are this step's rows: ``(K,)`` shared or ``(lanes, K)`` per-lane
@@ -571,9 +632,10 @@ def rans_decode_step(buf: jax.Array,    # (cap, lanes) uint8, lane-minor
     ``(lanes, topk)`` row of trial symbols.  Returns
     ``(s', ptr', symbols (lanes,), probes (lanes,), under (lanes,))`` —
     ``under`` counts refills that read past the stream end.  Designed to be
-    traced inside a ``lax.scan`` (interpret mode inlines the kernel into the
-    surrounding XLA program), with the initial ``(s, ptr)`` coming from
-    ``core.coder.decoder_init`` and ``buf`` transposed once outside the scan.
+    traced inside a ``lax.scan`` (off the TPU, interpret mode inlines the
+    kernel into the surrounding XLA program), with the initial ``(s, ptr)``
+    coming from ``core.coder.decoder_init`` and ``buf`` transposed once
+    outside the scan.
     """
     cap, lanes = buf.shape
     k = freq.shape[-1]
@@ -613,7 +675,7 @@ def rans_decode_step(buf: jax.Array,    # (cap, lanes) uint8, lane-minor
             jax.ShapeDtypeStruct((1, lanes), _I32),
             jax.ShapeDtypeStruct((1, lanes), _I32),
         ],
-        interpret=interpret,
+        interpret=common.interpret_mode(),
     )(buf, s.reshape(1, lanes), ptr.astype(_I32).reshape(1, lanes),
       freq_in, cdf_in, *extra_in)
     return s2[0], ptr2[0], sym[0], probes[0], under[0]

@@ -70,9 +70,11 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import constants as C
 from repro.core import update
 from repro.core.spc import TableSet
+from repro.kernels import common
 from repro.kernels.autotune import ring_size, select_encode_t_block
-from repro.kernels.common import (onehot_gather, onehot_gather_lanes,
-                                  onehot_scatter_rows, pad_chunk_rows)
+from repro.kernels.common import (SQUEEZED, chunk_row_spec, onehot_gather,
+                                  onehot_gather_lanes, onehot_scatter_rows,
+                                  pad_chunk_rows)
 
 _U32 = jnp.uint32
 _U8 = jnp.uint8
@@ -133,9 +135,10 @@ def _encode_plan(symbols: jax.Array, tbl: TableSet,
                 f"t_len={t_len}")
         layout = "perpos"
         planes_in = [pad_chunk_rows(p, t_len, chunk, n_chunks, padded_chunk)
-                     for p in planes]
+                     .reshape(-1, tb, k) for p in planes]
         tbl_specs = [pl.BlockSpec(
-            (tb, k), lambda i, c, j: (c * n_tb + n_tb - 1 - j, 0))] * 5
+            (SQUEEZED, tb, k),
+            lambda i, c, j: (c * n_tb + n_tb - 1 - j, 0, 0))] * 5
     elif ndim == 3:
         if tbl.freq.shape[0] != t_len or tbl.freq.shape[1] != lanes:
             raise ValueError(
@@ -151,9 +154,9 @@ def _encode_plan(symbols: jax.Array, tbl: TableSet,
         raise ValueError(f"unsupported table rank {ndim}")
 
     sym_in = pad_chunk_rows(symbols.T.astype(jnp.int32), t_len, chunk,
-                            n_chunks, padded_chunk)
-    sym_spec = pl.BlockSpec((tb, lane_block),
-                            lambda i, c, j: (c * n_tb + n_tb - 1 - j, i))
+                            n_chunks, padded_chunk).reshape(-1, tb, lanes)
+    sym_spec = pl.BlockSpec((SQUEEZED, tb, lane_block),
+                            lambda i, c, j: (c * n_tb + n_tb - 1 - j, 0, i))
     grid = (lanes // lane_block, n_chunks, n_tb)
     return _Plan(layout=layout, lanes=lanes, t_len=t_len, chunk=chunk,
                  n_chunks=n_chunks, tb=tb, n_tb=n_tb,
@@ -392,14 +395,13 @@ def _encode_fused_kernel(sym_ref, rcp_ref, rshift_ref, bias_ref, cmpl_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("chunk_size", "prob_bits", "lane_block",
-                                    "t_block", "interpret"))
+                                    "t_block"))
 def rans_encode_records(symbols: jax.Array,   # (lanes, T) int32
                        tbl: TableSet,
                        chunk_size: int | None = None,
                        prob_bits: int = C.PROB_BITS,
                        lane_block: int = 128,
-                       t_block: int | None = None,
-                       interpret: bool = True):
+                       t_block: int | None = None):
     """Records-path encode — the bytes-moved *reference* datapath.
 
     ONE ``pallas_call`` emitting fixed-shape renorm record planes
@@ -444,28 +446,27 @@ def rans_encode_records(symbols: jax.Array,   # (lanes, T) int32
                          lambda i, c, j: (c * p.n_tb + p.n_tb - 1 - j, 0, i)),
             pl.BlockSpec((p.tb, C.MAX_RENORM_STEPS, lane_block),
                          lambda i, c, j: (c * p.n_tb + p.n_tb - 1 - j, 0, i)),
-            pl.BlockSpec((1, lane_block), lambda i, c, j: (c, i)),
+            chunk_row_spec(lane_block),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((p.total_rows, C.MAX_RENORM_STEPS, lanes),
                                  _U8),
             jax.ShapeDtypeStruct((p.total_rows, C.MAX_RENORM_STEPS, lanes),
                                  _U8),
-            jax.ShapeDtypeStruct((p.n_chunks, lanes), _U32),
+            jax.ShapeDtypeStruct((p.n_chunks, 1, lanes), _U32),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, lane_block), _U32),   # encoder states across T
         ],
-        interpret=interpret,
+        interpret=common.interpret_mode(),
     )(p.sym_in, *p.planes_in)
     shape = (p.n_chunks, p.padded_chunk, C.MAX_RENORM_STEPS, lanes)
-    return rec_b.reshape(shape), rec_m.reshape(shape), states
+    return rec_b.reshape(shape), rec_m.reshape(shape), states[:, 0]
 
 
 @functools.partial(jax.jit,
                    static_argnames=("cap", "chunk_size", "prob_bits",
-                                    "lane_block", "t_block", "interpret",
-                                    "scatter"))
+                                    "lane_block", "t_block", "scatter"))
 def rans_encode_lanes(symbols: jax.Array,   # (lanes, T) int32
                       tbl: TableSet,
                       cap: int,
@@ -473,7 +474,6 @@ def rans_encode_lanes(symbols: jax.Array,   # (lanes, T) int32
                       prob_bits: int = C.PROB_BITS,
                       lane_block: int = 128,
                       t_block: int | None = None,
-                      interpret: bool = True,
                       scatter: str = "ring"):
     """Fused-compaction encode — ONE ``pallas_call``, packed streams out.
 
@@ -545,19 +545,13 @@ def rans_encode_lanes(symbols: jax.Array,   # (lanes, T) int32
         in_specs=[p.sym_spec] + p.tbl_specs,
         out_specs=[
             pl.BlockSpec((1, cap, lane_block), lambda i, c, j: (c, 0, i)),
-            pl.BlockSpec((1, lane_block), lambda i, c, j: (c, i)),
-            pl.BlockSpec((1, lane_block), lambda i, c, j: (c, i)),
-            pl.BlockSpec((1, lane_block), lambda i, c, j: (c, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((p.n_chunks, cap, lanes), _U8),
-            jax.ShapeDtypeStruct((p.n_chunks, lanes), _I32),
-            jax.ShapeDtypeStruct((p.n_chunks, lanes), _I32),
-            jax.ShapeDtypeStruct((p.n_chunks, lanes), _I32),
-        ],
+        ] + [chunk_row_spec(lane_block)] * 3,
+        out_shape=[jax.ShapeDtypeStruct((p.n_chunks, cap, lanes), _U8)]
+        + [jax.ShapeDtypeStruct((p.n_chunks, 1, lanes), _I32)] * 3,
         scratch_shapes=scratch,
-        interpret=interpret,
+        interpret=common.interpret_mode(),
     )(p.sym_in, *p.planes_in)
     # (n_chunks, cap, lanes) -> the ChunkedLanes (n_chunks, lanes, cap)
     # device form (the mirror of the decode kernel's input transpose)
-    return buf.swapaxes(1, 2), start, length, ovf.astype(jnp.bool_)
+    return (buf.swapaxes(1, 2), start[:, 0], length[:, 0],
+            ovf[:, 0].astype(jnp.bool_))

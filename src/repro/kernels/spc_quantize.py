@@ -12,8 +12,9 @@ an MXU-shaped reformulation that produces *identical* integer frequencies:
 and the negative-delta waterfill's exclusive prefix-capacity becomes a masked
 matrix-vector product  cum_excl(i) = sum_j [rank_asc(j) < rank_asc(i)] cap(j).
 
-VMEM: the (Bb, K, K) comparison cube dominates — Bb=8, K=256 -> 4 MB fp32.
-Tile the batch dim via the grid for larger alphabets.
+The kernel walks its ``Bb`` rows one at a time and each row's (i, j)
+comparison planes ``_I_BLOCK`` rows of i at a time, so VMEM holds
+(32, K) planes: K=256 -> 32 KB per int32 plane.
 """
 
 from __future__ import annotations
@@ -25,54 +26,78 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core import constants as C
+from repro.kernels import common
 
 _U32 = jnp.uint32
 _I32 = jnp.int32
 
 
+# rows i of the (i, j) comparison planes per block: the planes of one block
+# stay small enough for the vector unit's scoped VMEM at any K
+_I_BLOCK = 32
+
+
 def _spc_quantize_kernel(p_ref, freq_ref, *, prob_bits: int):
     total = 1 << prob_bits
-    k = p_ref.shape[1]
-    # single BF16 -> fixed-point conversion (the paper's one-shot cast)
-    p = p_ref[...].astype(jnp.bfloat16).astype(jnp.float32)
-    p = jnp.where(jnp.isfinite(p) & (p > 0), p, 0.0)
-    scaled = p * jnp.float32(total)
-    f0 = jnp.maximum(1, jnp.round(scaled)).astype(_I32)       # (B, K)
-    delta = total - jnp.sum(f0, axis=1, keepdims=True)        # (B, 1)
-    resid = scaled - f0.astype(jnp.float32)
+    bb, k = p_ref.shape
+    blocks = [(i0, min(_I_BLOCK, k - i0)) for i0 in range(0, k, _I_BLOCK)]
 
-    # pairwise stable ranks (dense comparisons instead of argsort)
-    ri = resid[:, :, None]                                    # (B, K, 1)
-    rj = resid[:, None, :]                                    # (B, 1, K)
-    jlt = jax.lax.broadcasted_iota(_I32, (1, k, k), 2) < \
-        jax.lax.broadcasted_iota(_I32, (1, k, k), 1)          # j < i
-    eq_tie = (rj == ri) & jlt
-    rank_desc = jnp.sum(((rj > ri) | eq_tie).astype(_I32), axis=2)
-    rank_asc = jnp.sum(((rj < ri) | eq_tie).astype(_I32), axis=2)
+    def per_i(fn):
+        """Row ``(1, K)`` of ``fn(i0, n)``'s per-block ``(n,)`` values."""
+        return jnp.concatenate([fn(i0, n).reshape(1, n) for i0, n in blocks],
+                               axis=1)
 
-    # delta > 0: base share + largest-remainder top-up
-    f_pos = f0 + delta // k + (rank_desc < delta % k).astype(_I32)
+    # one distribution per iteration, its (i, j) planes one block of i
+    # at a time: a (Bb, K, K) cube leaves the TPU compiler unrolling far
+    # more code than it finishes compiling, and whole (K, K) planes
+    # overflow the scoped VMEM
+    def one_row(b, carry):
+        # single BF16 -> fixed-point conversion (the paper's one-shot cast)
+        p = p_ref[pl.ds(b, 1), :].astype(jnp.bfloat16).astype(jnp.float32)
+        p = jnp.where(jnp.isfinite(p) & (p > 0), p, 0.0)
+        scaled = p * jnp.float32(total)
+        f0 = jnp.maximum(1, jnp.round(scaled)).astype(_I32)   # (1, K)
+        delta = total - jnp.sum(f0, axis=1, keepdims=True)    # (1, 1)
+        resid = scaled - f0.astype(jnp.float32)
+        col = jnp.transpose(resid)                            # (K, 1)
 
-    # delta < 0: waterfill smallest residual first, capacity f0 - 1
-    need = -delta                                             # (B, 1)
-    cap = f0 - 1                                              # (B, K)
-    before = (rank_asc[:, None, :] < rank_asc[:, :, None])    # (B, i, j)
-    cum_excl = jnp.sum(before.astype(jnp.float32)
-                       * cap[:, None, :].astype(jnp.float32),
-                       axis=2).astype(_I32)
-    take = jnp.clip(need - cum_excl, 0, cap)
-    f_neg = f0 - take
+        # pairwise stable ranks (dense comparisons instead of argsort)
+        def rank(cmp):
+            def block(i0, n):
+                ri = col[i0:i0 + n]                           # (n, 1)
+                jlt = (jax.lax.broadcasted_iota(_I32, (n, k), 1)
+                       < i0 + jax.lax.broadcasted_iota(_I32, (n, k), 0))
+                hit = cmp(resid, ri) | ((resid == ri) & jlt)
+                return jnp.sum(hit.astype(_I32), axis=1)
+            return per_i(block)
+        rank_desc = rank(jnp.greater)
+        rank_asc = rank(jnp.less)
 
-    f = jnp.where(delta >= 0, f_pos, f_neg)
-    freq_ref[...] = f.astype(_U32)
+        # delta > 0: base share + largest-remainder top-up
+        f_pos = f0 + delta // k + (rank_desc < delta % k).astype(_I32)
+
+        # delta < 0: waterfill smallest residual first, capacity f0 - 1;
+        # the exclusive prefix capacity sums at most K * 2**prob_bits, so
+        # the int32 sum is exact
+        need = -delta
+        cap = f0 - 1
+        asc_col = jnp.transpose(rank_asc)                     # (K, 1)
+        cum_excl = per_i(lambda i0, n: jnp.sum(
+            jnp.where(rank_asc < asc_col[i0:i0 + n], cap, 0), axis=1))
+        take = jnp.clip(need - cum_excl, 0, cap)
+        f_neg = f0 - take
+
+        f = jnp.where(delta >= 0, f_pos, f_neg)
+        freq_ref[pl.ds(b, 1), :] = f.astype(_U32)
+        return carry
+
+    jax.lax.fori_loop(0, bb, one_row, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("prob_bits", "batch_block",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("prob_bits", "batch_block"))
 def spc_quantize(probs: jax.Array,          # (B, K) float
                  prob_bits: int = C.PROB_BITS,
-                 batch_block: int = 8,
-                 interpret: bool = True) -> jax.Array:
+                 batch_block: int = 8) -> jax.Array:
     """Batched BF16->fixed-point quantization.  Returns (B, K) uint32 freqs."""
     b, k = probs.shape
     if b % batch_block:
@@ -83,5 +108,5 @@ def spc_quantize(probs: jax.Array,          # (B, K) float
         in_specs=[pl.BlockSpec((batch_block, k), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((batch_block, k), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, k), _U32),
-        interpret=interpret,
+        interpret=common.interpret_mode(),
     )(probs.astype(jnp.float32))

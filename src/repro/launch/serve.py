@@ -4,15 +4,20 @@
         --symbols 256
     python -m repro.launch.serve --mode engine --streams 6 --slots 2 \
         --arrival-rate 0.5
+    python -m repro.launch.serve --full --mode engine --lanes 32 \
+        --symbols 1536 --chunk-size 256 --slots 4 --streams 8
 
 ``--mode compress`` runs one stream end to end: SPC + multi-lane rANS
 encode, prediction-guided decode, bit-exactness check — the full Fig. 2
 datapath.  ``--mode engine`` drives the batched multi-stream engine
 instead: ``--streams`` requests with seeded Poisson arrivals
 (``--arrival-rate`` per virtual tick) are continuously batched into
-``--slots`` slots of one traced step program, every round-tripped stream
-is verified byte-identical to the single-request path, and per-request
-latency (admission wait included) is reported.
+``--slots`` slots of one traced step program, every blob is verified
+byte-identical to the single-request path and decompressed again through
+the engine, and per-request latency (admission wait included) is
+reported.  ``--full`` serves the arch's registered full-width config
+instead of its smoke-sized cut.  Pallas kernels compile on a TPU and run
+in the interpreter elsewhere.
 """
 
 from __future__ import annotations
@@ -24,9 +29,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import ARCH_IDS, get_smoke_config
+from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.core import bitstream
 from repro.data.pipeline import token_stream
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import init_model, state_spec
 from repro.serve.compress import lm_compress, lm_decompress
 from repro.serve.engine import generate
@@ -40,6 +46,9 @@ def main(argv=None):
                          " — the serve stack is family-agnostic behind the "
                          "model-state protocol: SSM / rGLRU / MoE smoke "
                          "configs all run the same datapath")
+    ap.add_argument("--full", action="store_true",
+                    help="serve the arch's registered full-width config "
+                         "instead of its smoke-sized cut")
     ap.add_argument("--mode", choices=["compress", "generate", "engine"],
                     default="compress",
                     help="compress = one stream end to end; generate = "
@@ -63,24 +72,26 @@ def main(argv=None):
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--topk", type=int, default=4)
     ap.add_argument("--backend", choices=["coder", "kernel", "two_pass"],
-                    default="coder",
-                    help="rANS datapath: 'coder' = pure-JAX lane coder; "
-                         "'kernel' = Pallas encode + the FUSED serve decode "
-                         "(one program: model step + SPC + per-step decode "
-                         "kernel); 'two_pass' = Pallas encode + the "
-                         "collect-then-replay reference decode "
-                         "(interpret mode off-TPU)")
+                    default="kernel",
+                    help="rANS datapath: 'kernel' = Pallas encode + the "
+                         "FUSED serve decode (one program: model step + SPC "
+                         "+ per-step decode kernel; the engine's step "
+                         "program runs the same kernel); 'coder' = pure-JAX "
+                         "lane coder (the reference); 'two_pass' = Pallas "
+                         "encode + the collect-then-replay reference decode")
     args = ap.parse_args(argv)
 
     if args.arch not in ARCH_IDS:
         ap.error(f"unknown --arch {args.arch!r}; registered ids: "
                  f"{', '.join(ARCH_IDS)}")
-    cfg = get_smoke_config(args.arch)
+    setup_compile_cache()
+    cfg = (get_config if args.full else get_smoke_config)(args.arch)
     spec = state_spec(cfg)
     state_kind = ("ring+recurrent" if spec.ring and spec.recurrent
                   else "recurrent" if spec.recurrent else "ring")
-    print(f"arch={args.arch} family={cfg.family} kinds={spec.kinds} "
-          f"state={state_kind}")
+    print(f"arch={args.arch} config={cfg.name} family={cfg.family} "
+          f"kinds={spec.kinds} state={state_kind} "
+          f"device={jax.devices()[0].device_kind}")
     params = init_model(cfg, jax.random.PRNGKey(0))
     if args.ckpt:
         step = checkpoint.latest_step(args.ckpt)
@@ -101,9 +112,10 @@ def main(argv=None):
                                            (args.lanes, args.symbols),
                                            seed=100 + i), np.int32)
                    for i in range(args.streams)]
+        backend = "coder" if args.backend == "coder" else "kernel"
         eng = BatchEngine(params, cfg, slots=args.slots, lanes=args.lanes,
                           chunk_size=args.chunk_size,
-                          max_len=args.symbols)
+                          max_len=args.symbols, step_backend=backend)
         rids = [eng.submit_compress(s, arrival=float(a))
                 for s, a in zip(streams, arrivals)]
         t0 = time.time()
@@ -114,7 +126,8 @@ def main(argv=None):
             r = res[rid]
             assert r.ok, r.error
             stats = lm_compress_chunked(params, cfg, jnp.asarray(toks),
-                                        chunk_size=args.chunk_size)
+                                        chunk_size=args.chunk_size,
+                                        backend=backend)
             enc = jax.tree.map(np.asarray, stats.chunks)
             ref = bitstream.pack_chunked(enc.buf, enc.start, enc.length,
                                          enc.overflow,
@@ -123,14 +136,25 @@ def main(argv=None):
             assert r.blob == ref, f"request {rid}: engine blob diverged"
             lat.append(r.completed_at - r.arrival)
         lat = np.sort(np.asarray(lat))
+        back = [eng.submit_decompress(res[rid].blob) for rid in rids]
+        t0 = time.time()
+        res_back = eng.run(clock="virtual")
+        wall_back = time.time() - t0
+        for rid, toks in zip(back, streams):
+            r = res_back[rid]
+            assert r.ok, r.error
+            assert np.array_equal(r.tokens, toks), (
+                f"request {rid}: engine decompress diverged")
         print(f"engine: {args.streams} streams x {args.lanes} lanes x "
-              f"{args.symbols} symbols through {args.slots} slots")
-        print(f"  wall {wall:.2f}s  throughput "
-              f"{args.streams / wall:.2f} streams/s")
+              f"{args.symbols} symbols through {args.slots} slots "
+              f"(step backend {backend})")
+        print(f"  compress wall {wall:.2f}s (prefill cycles "
+              f"{eng.prefill_cycles})  decompress wall {wall_back:.2f}s, "
+              "first runs include compilation")
         print(f"  virtual latency (ticks): p50 {np.percentile(lat, 50):.1f} "
               f" p99 {np.percentile(lat, 99):.1f}")
         print(f"  all {args.streams} blobs byte-identical to the "
-              "single-request path")
+              "single-request path and decompressed exactly by the engine")
         return
 
     if args.mode == "generate":

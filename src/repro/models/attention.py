@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.config import ModelConfig
-from repro.models.layers import apply_rope, rmsnorm
+from repro.models.layers import apply_rope, rmsnorm, tree_sum
 from repro.models.param import ParamDef
 
 _NEG = -1e30
@@ -81,7 +81,7 @@ def _ring_sum(e: jax.Array) -> jax.Array:
     eb = _ring_blocks(e, -1)
 
     def body(acc, blk):
-        return acc + jnp.sum(blk, axis=-1), None
+        return acc + tree_sum(blk), None
 
     acc, _ = jax.lax.scan(body, jnp.zeros(e.shape[:-1], e.dtype), eb)
     return acc
@@ -256,26 +256,25 @@ def _attend_slots(q: jax.Array, ck: jax.Array, cv: jax.Array,
     """
     scale = 1.0 / math.sqrt(cfg.head_dim_)
     hp, kv = cfg.n_heads_padded, cfg.n_kv_heads
-    grouped = kv > 0 and hp % kv == 0
-    # Scores are computed per _RING_BLOCK tile of cache slots: a full-width
-    # GEMM rounds its remainder columns (cache_len % vector width) through a
-    # different instruction path, so the same slot's score drifts ~1 ulp
-    # with the ring length.  Per-tile GEMMs have one fixed shape.
-    if grouped:
-        # §Perf: grouped GQA decode — contract q-head groups against the kv
-        # cache directly, never materializing the (S, H) expanded cache
-        # (16x the cache bytes for kv=8, H=128).
-        g = hp // kv
-        qg = q.reshape(q.shape[0], 1, kv, g, q.shape[-1])
-        sb = jax.lax.map(
-            lambda kb: jnp.einsum("bqhgd,bkhd->bhgqk", qg, kb,
-                                  preferred_element_type=jnp.float32),
-            _ring_blocks(ck, 1))
-    else:
-        sb = jax.lax.map(
-            lambda kb: jnp.einsum("bqhd,bkhd->bhqk", q, kb,
-                                  preferred_element_type=jnp.float32),
-            _ring_blocks(_expand_kv(ck, cfg), 1))
+    if not (kv > 0 and hp % kv == 0):
+        ck, cv, kv = _expand_kv(ck, cfg), _expand_kv(cv, cfg), hp
+    # §Perf: grouped GQA decode — q-head groups contract against the kv
+    # cache directly, never materializing the (S, H) expanded cache (16x
+    # the cache bytes for kv=8, H=128).  Every contraction below is an
+    # elementwise product summed by ``tree_sum``, tile by tile of
+    # _RING_BLOCK cache slots: a contraction or reduce lets the compiler
+    # choose the summation order per program (on the TPU it differs with
+    # the row count), and the order must be one fixed function of the
+    # shapes the step path shares with the prefill path.
+    g = hp // kv
+    qg = q[:, 0].reshape(q.shape[0], kv, g, 1, 1, q.shape[-1])
+    qg = qg.astype(jnp.float32)
+
+    def scores(kb):                                  # (B, BLOCK, KV, Dh)
+        kt = jnp.moveaxis(kb, 1, 2)[:, :, None, None].astype(jnp.float32)
+        return tree_sum(qg * kt)                     # (B, KV, G, 1, BLOCK)
+
+    sb = jax.lax.map(scores, _ring_blocks(ck, 1))
     # (nb, ..., BLOCK) -> (..., nb * BLOCK): the padded cache axis
     sb = jnp.moveaxis(sb, 0, -2)
     s = sb.reshape(sb.shape[:-2] + (-1,)) * scale
@@ -291,13 +290,14 @@ def _attend_slots(q: jax.Array, ck: jax.Array, cv: jax.Array,
     m = jnp.max(s, axis=-1, keepdims=True)
     e = jnp.where(vmask, jnp.exp(s - m), 0.0)
     prob = (e / _ring_sum(e)[..., None]).astype(q.dtype)
-    if grouped:
-        out = _ring_attn(prob, cv,
-                         lambda pb, vb: jnp.einsum("bhgqk,bkhd->bqhgd",
-                                                   pb, vb))
-        return out.reshape(out.shape[0], 1, hp, out.shape[-1])
-    return _ring_attn(prob, _expand_kv(cv, cfg),
-                      lambda pb, vb: jnp.einsum("bhqk,bkhd->bqhd", pb, vb))
+
+    def values(pb, vb):             # (B, KV, G, 1, BLOCK), (B, BLOCK, KV, Dh)
+        vt = jnp.moveaxis(vb, 1, 2)[:, :, None, None]
+        return tree_sum(pb[..., None] * vt, axis=-2)  # (B, KV, G, 1, Dh)
+
+    out = _ring_attn(prob, cv, values)
+    return jnp.moveaxis(out, 3, 1).reshape(out.shape[0], 1, hp,
+                                           out.shape[-1])
 
 
 def attn_decode(p: dict, x1: jax.Array, cache: dict, pos: jax.Array,
@@ -364,12 +364,14 @@ def attn_prefill(p: dict, xs: jax.Array, cache: dict, pos0: jax.Array,
     per-row chunk start and live step count (rows beyond ``n_valid`` are
     frozen — their queries are computed and discarded, nothing is written).
 
-    Identity argument: projections/norms are batch-extent-independent on
-    the target backend (each output element of a GEMM/rmsnorm is its own
-    fixed-order reduction), and the attend itself runs the SAME q-extent-1
-    :func:`_attend_slots` core as the step path, ``lax.map``-ed over the S
-    positions — a multi-query score/value einsum rounds ~1 ulp differently
-    than S single-query ones, so the shapes must literally match.  The one
+    Identity argument: the projections are batch-extent-independent GEMMs
+    (checked on the TPU as well as the CPU), the norms sum in a fixed
+    order (:func:`repro.models.layers.tree_sum` — a compiler-chosen reduce
+    is not extent-independent on the TPU), and the attend itself runs the
+    SAME q-extent-1 :func:`_attend_slots` core as the step path,
+    ``lax.map``-ed over the S positions — a multi-query score/value einsum
+    rounds ~1 ulp differently than S single-query ones, so the shapes must
+    literally match.  The one
     structural divergence — this writes all S entries before any query
     attends — is masked out: a future in-chunk entry is ``valid=False``
     for earlier queries exactly where the step path would have seen a dead

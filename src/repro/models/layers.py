@@ -22,12 +22,34 @@ def make_rmsnorm(d: int) -> dict:
     return {"scale": ParamDef((d,), ("embed",), init="ones")}
 
 
+def tree_sum(x: jax.Array, axis: int = -1) -> jax.Array:
+    """Sum over ``axis`` as a fixed binary tree of elementwise adds.
+
+    The association order depends on the axis length alone.  A reduce (or
+    a contraction) lets the compiler pick the order per program: on the
+    TPU the same row's sum of squares rounds differently when the array
+    holds 1 or 256 positions, which is enough to flip a quantized coding
+    table between the engine's prefill and step programs.  Elementwise
+    adds of slices cannot be reordered.  The axis is zero-padded to a
+    power of two (adding +0.0 is exact).
+    """
+    x = jnp.moveaxis(x, axis, -1)
+    n = x.shape[-1]
+    width = 1 << max(n - 1, 0).bit_length()
+    if width != n:
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - n)])
+    while x.shape[-1] > 1:
+        half = x.shape[-1] // 2
+        x = x[..., :half] + x[..., half:]
+    return x[..., 0]
+
+
 def rmsnorm(p: dict, x: jax.Array, eps: float = 1e-5) -> jax.Array:
     dt = x.dtype
-    # f32-accumulated second moment without materializing an f32 copy of x
-    # (§Perf memory-term lever; bf16 squares are exact in f32)
-    var = jnp.einsum("...d,...d->...", x, x,
-                     preferred_element_type=jnp.float32)[..., None]
+    # f32 second moment (bf16 squares are exact in f32), summed in a fixed
+    # order so a row normalizes identically at any batch or chunk extent
+    xf = x.astype(jnp.float32)
+    var = tree_sum(xf * xf)[..., None]
     var = var / x.shape[-1]
     inv = jax.lax.rsqrt(var + eps)
     return (x.astype(jnp.float32) * inv

@@ -93,16 +93,35 @@ def block_forward(kind: str, p: dict, x: jax.Array, cfg: ModelConfig,
     return x + h, aux
 
 
+# Serve-path sublayers sit behind optimization barriers.  The TPU compiler
+# fuses across sublayer boundaries differently for a 256-position prefill
+# block than for a one-position step, and the fused code rounds a row
+# differently (measured on a v5e: step and prefill logits differed at 256
+# positions, matched at 64 and 128, and matched at 256 with barriers); the
+# engine's byte identity needs prefill bitwise equal to the step scan.
+_bar = jax.lax.optimization_barrier
+
+
+def _norm(p: dict, x: jax.Array, eps: float) -> jax.Array:
+    return _bar(rmsnorm(p, _bar(x), eps))
+
+
+def _ffn(p: dict, x: jax.Array) -> jax.Array:
+    return _bar(mlp(p, _bar(x)))
+
+
 def block_decode(kind: str, p: dict, x1: jax.Array, cache: dict,
                  pos: jax.Array, cfg: ModelConfig,
                  mem: jax.Array | None = None):
-    h = rmsnorm(p["ln1"], x1, cfg.norm_eps)
+    h = _norm(p["ln1"], x1, cfg.norm_eps)
     new_cache = cache
     if kind in ("attn", "attn_moe", "dec"):
         h, kv = attn_decode(p["attn"], h, cache["kv"], pos, cfg)
+        h = _bar(h)
         new_cache = dict(cache, kv=kv)
     elif kind == "cross":
         h, _ = attn_decode(p["cross"], h, None, pos, cfg, mem=mem)
+        h = _bar(h)
     elif kind == "ssm":
         y, st = ssm_decode_step(p["ssm"], h, cache["ssm"], cfg)
         return x1 + y, dict(cache, ssm=st)
@@ -111,14 +130,14 @@ def block_decode(kind: str, p: dict, x1: jax.Array, cache: dict,
         new_cache = dict(cache, rec=st)
     x1 = x1 + h
     if kind == "dec":
-        h = rmsnorm(p["ln_cross"], x1, cfg.norm_eps)
+        h = _norm(p["ln_cross"], x1, cfg.norm_eps)
         y, _ = attn_decode(p["cross"], h, None, pos, cfg, mem=mem)
-        x1 = x1 + y
-    h = rmsnorm(p["ln2"], x1, cfg.norm_eps)
+        x1 = x1 + _bar(y)
+    h = _norm(p["ln2"], x1, cfg.norm_eps)
     if kind == "attn_moe":
         h, _ = moe(p["ffn"], h, cfg)
     else:
-        h = mlp(p["ffn"], h)
+        h = _ffn(p["ffn"], h)
     return x1 + h, new_cache
 
 
@@ -127,15 +146,15 @@ def block_prefill(kind: str, p: dict, xs: jax.Array, cache: dict,
     """Teacher-forced block over S positions — bit-identical to S
     sequential ``block_decode`` steps (attention kinds only; see
     :func:`can_prefill`)."""
-    h = rmsnorm(p["ln1"], xs, cfg.norm_eps)
+    h = _norm(p["ln1"], xs, cfg.norm_eps)
     h, kv = attn_prefill(p["attn"], h, cache["kv"], pos0, n_valid, cfg)
     new_cache = dict(cache, kv=kv)
-    xs = xs + h
-    h = rmsnorm(p["ln2"], xs, cfg.norm_eps)
+    xs = xs + _bar(h)
+    h = _norm(p["ln2"], xs, cfg.norm_eps)
     if kind == "attn_moe":
         h, _ = moe(p["ffn"], h, cfg)
     else:
-        h = mlp(p["ffn"], h)
+        h = _ffn(p["ffn"], h)
     return xs + h, new_cache
 
 
@@ -366,7 +385,7 @@ def decode_step(params: dict, cache: dict, token: jax.Array, pos: jax.Array,
         x1, c = stage_decode(params["stages"][f"s{i}"], cache[f"s{i}"], x1,
                              pos, pat, cfg, memory)
         new_cache[f"s{i}"] = c
-    x1 = rmsnorm(params["final_norm"], x1, cfg.norm_eps)
+    x1 = _norm(params["final_norm"], x1, cfg.norm_eps)
     lg = logits(params["tok"], x1, cfg)[:, 0]
     return lg, new_cache
 
@@ -388,7 +407,7 @@ def prefill_chunk(params: dict, cache: dict, tokens: jax.Array,
         xs, c = stage_prefill(params["stages"][f"s{i}"], cache[f"s{i}"], xs,
                               pos0, n_valid, pat, cfg)
         new_cache[f"s{i}"] = c
-    xs = rmsnorm(params["final_norm"], xs, cfg.norm_eps)
+    xs = _norm(params["final_norm"], xs, cfg.norm_eps)
     return logits(params["tok"], xs, cfg), new_cache
 
 

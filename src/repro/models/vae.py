@@ -214,8 +214,7 @@ def _bins(z_bins: int):
 
 
 def bb_encode(st: stack.StackState, params: dict, x: jax.Array,
-              cfg: VAEConfig, backend: str = "coder",
-              interpret: bool = True) -> stack.StackState:
+              cfg: VAEConfig, backend: str = "coder") -> stack.StackState:
     """Bits-back encode one ``(lanes, d_x)`` batch onto the stack (the
     A-E Bit-Swap schedule in the module docstring).  The net message cost
     is ``stack.stack_bytes`` growth — the posterior pop's recovered bits
@@ -227,8 +226,7 @@ def bb_encode(st: stack.StackState, params: dict, x: jax.Array,
     # A: pop k1 ~ q1(. | x)
     mu1, sig1 = _mu_sig(_net(params["enc1"], xn))
     f1, c1 = _latent_tables(mu1, sig1, edges, pb)
-    st, k1 = stack.pop_symbols(st, cfg.d_z, f1, c1, pb, backend=backend,
-                               interpret=interpret)
+    st, k1 = stack.pop_symbols(st, cfg.d_z, f1, c1, pb, backend=backend)
     z1c = centres[k1]
 
     # B: push x ~ p(x | z1)
@@ -238,8 +236,7 @@ def bb_encode(st: stack.StackState, params: dict, x: jax.Array,
     # C: pop k2 ~ q2(. | z1)
     mu2, sig2 = _mu_sig(_net(params["enc2"], z1c))
     f2, c2 = _latent_tables(mu2, sig2, edges, pb)
-    st, k2 = stack.pop_symbols(st, cfg.d_z, f2, c2, pb, backend=backend,
-                               interpret=interpret)
+    st, k2 = stack.pop_symbols(st, cfg.d_z, f2, c2, pb, backend=backend)
     z2c = centres[k2]
 
     # D: push k1 ~ p(z1 | z2)
@@ -253,7 +250,7 @@ def bb_encode(st: stack.StackState, params: dict, x: jax.Array,
 
 
 def bb_decode(st: stack.StackState, params: dict, cfg: VAEConfig,
-              backend: str = "coder", interpret: bool = True):
+              backend: str = "coder"):
     """Exact reverse of :func:`bb_encode` (push and pop swapped, E' -> A').
     Returns ``(state, x)``; the state equals the pre-encode stack
     bit-for-bit — the bits-back identity."""
@@ -262,15 +259,13 @@ def bb_decode(st: stack.StackState, params: dict, cfg: VAEConfig,
 
     # E': pop k2 ~ p(z2)
     fu, cu = _uniform_tables(cfg.z_bins, pb)
-    st, k2 = stack.pop_symbols(st, cfg.d_z, fu, cu, pb, backend=backend,
-                               interpret=interpret)
+    st, k2 = stack.pop_symbols(st, cfg.d_z, fu, cu, pb, backend=backend)
     z2c = centres[k2]
 
     # D': pop k1 ~ p(z1 | z2)
     mu1p, sig1p = _mu_sig(_net(params["dec2"], z2c))
     fp, cp = _latent_tables(mu1p, sig1p, edges, pb)
-    st, k1 = stack.pop_symbols(st, cfg.d_z, fp, cp, pb, backend=backend,
-                               interpret=interpret)
+    st, k1 = stack.pop_symbols(st, cfg.d_z, fp, cp, pb, backend=backend)
     z1c = centres[k1]
 
     # C': push k2 ~ q2(. | z1)
@@ -280,8 +275,7 @@ def bb_decode(st: stack.StackState, params: dict, cfg: VAEConfig,
 
     # B': pop x ~ p(x | z1)
     fx, cx = _pixel_tables(params, z1c, cfg)
-    st, x = stack.pop_symbols(st, cfg.d_x, fx, cx, pb, backend=backend,
-                              interpret=interpret)
+    st, x = stack.pop_symbols(st, cfg.d_x, fx, cx, pb, backend=backend)
 
     # A': push k1 ~ q1(. | x)
     xn = normalize(x, cfg.x_bins)

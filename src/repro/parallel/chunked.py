@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core import bitstream, coder, constants as C
 from repro.core.bitstream import ContainerSlab
@@ -112,8 +112,7 @@ def _chunked_table_specs(tbl: TableSet, sharded: bool):
 def encode_chunked(symbols: jax.Array, tbl: TableSet, chunk_size: int,
                    mesh: Mesh | None = None,
                    cap: int | None = None,
-                   backend: str = "coder",
-                   interpret: bool = True) -> ChunkedLanes:
+                   backend: str = "coder") -> ChunkedLanes:
     """Device-parallel chunked encode over either encode backend.
 
     Full chunks are sharded over the mesh's ``chunks`` axis; per-position
@@ -121,9 +120,9 @@ def encode_chunked(symbols: jax.Array, tbl: TableSet, chunk_size: int,
     ``backend="coder"`` runs the pure-JAX lane encoder (vmap over the local
     chunk slab); ``backend="kernel"`` runs the fused-compaction Pallas
     encode kernel — one ``pallas_call`` per device covering its whole local
-    slab (the kernel's chunk grid axis, interpret mode on CPU) and emitting
-    packed streams directly (no host-side ``compact_records`` pass).  Both
-    consume ``core.update``, so the produced streams — and the per-cell
+    slab (the kernel's chunk grid axis) and emitting packed streams
+    directly (no host-side ``compact_records`` pass).  Both consume
+    ``core.update``, so the produced streams — and the per-cell
     overflow flags — are byte-identical across backends and mesh shapes.
     Falls back to the single-device path whenever the mesh cannot evenly
     take the chunk axis.
@@ -139,7 +138,7 @@ def encode_chunked(symbols: jax.Array, tbl: TableSet, chunk_size: int,
     if not _usable(mesh, n_full):
         if backend == "kernel":
             return kops.rans_encode_chunked(symbols, tbl, chunk_size,
-                                            cap=cap, interpret=interpret)
+                                            cap=cap)
         return coder.encode_chunked(symbols, tbl, chunk_size, cap=cap)
 
     per_position = coder.is_per_position(tbl, t_len)
@@ -162,7 +161,7 @@ def encode_chunked(symbols: jax.Array, tbl: TableSet, chunk_size: int,
                 lambda a: a.reshape((n_loc * chunk_size,) + a.shape[2:]),
                 tbl_loc) if chunk_major else tbl_loc)
             ch = kops.rans_encode_chunked(flat, tbl_flat, chunk_size,
-                                          cap=cap, interpret=interpret)
+                                          cap=cap)
             return EncodedLanes(ch.buf, ch.start, ch.length, ch.overflow)
         if chunk_major:
             return jax.vmap(lambda s, tb: coder.encode(s, tb, cap=cap))(
@@ -172,18 +171,18 @@ def encode_chunked(symbols: jax.Array, tbl: TableSet, chunk_size: int,
     spec = P("chunks")
     out_specs = EncodedLanes(buf=spec, start=spec, length=spec,
                              overflow=spec)
-    check_rep = {"check_rep": False} if backend == "kernel" else {}
+    check_vma = {"check_vma": False} if backend == "kernel" else {}
     if per_position:
         tbl_full = coder.chunk_tables(tbl, n_full, chunk_size)
         enc = shard_map(lambda s, tb: _slab_encode(s, tb, True), mesh=mesh,
                         in_specs=(spec,
                                   _chunked_table_specs(tbl, sharded=True)),
-                        out_specs=out_specs, **check_rep)(full, tbl_full)
+                        out_specs=out_specs, **check_vma)(full, tbl_full)
     else:
         enc = shard_map(lambda s, tb: _slab_encode(s, tb, False), mesh=mesh,
                         in_specs=(spec,
                                   _chunked_table_specs(tbl, sharded=False)),
-                        out_specs=out_specs, **check_rep)(full, tbl)
+                        out_specs=out_specs, **check_vma)(full, tbl)
     enc = ChunkedLanes(buf=enc.buf, start=enc.start, length=enc.length,
                        overflow=enc.overflow)
 
@@ -192,8 +191,7 @@ def encode_chunked(symbols: jax.Array, tbl: TableSet, chunk_size: int,
                     if per_position else tbl)
         sym_tail = symbols[:, n_full * chunk_size:]
         if backend == "kernel":
-            tail = kops.rans_encode(sym_tail, tbl_tail, cap=cap,
-                                    interpret=interpret)
+            tail = kops.rans_encode(sym_tail, tbl_tail, cap=cap)
         else:
             tail = coder.encode(sym_tail, tbl_tail, cap=cap)
         enc = jax.tree.map(
@@ -208,16 +206,15 @@ def decode_chunked(chunks: ChunkedLanes | ContainerSlab, n_symbols: int,
                    chunk_size: int, mesh: Mesh | None = None,
                    prob_bits: int = C.PROB_BITS, use_lut: bool = False,
                    predictor=None, backend: str = "coder",
-                   candidates: jax.Array | None = None,
-                   interpret: bool = True):
+                   candidates: jax.Array | None = None):
     """Device-parallel chunked decode over either decode backend.
 
     ``backend="coder"`` runs the pure-JAX lane decoder (vmap per local
     chunk slab); ``backend="kernel"`` runs the Pallas decode kernel — one
     ``pallas_call`` per device covering its whole local slab (the kernel's
-    chunk grid axis, interpret mode on CPU).  Both consume ``core.search``,
-    so the returned (symbols (lanes, T), avg_probes) are bit-identical
-    across backends and mesh shapes (chunks carry no cross-device state).
+    chunk grid axis).  Both consume ``core.search``, so the returned
+    (symbols (lanes, T), avg_probes) are bit-identical across backends and
+    mesh shapes (chunks carry no cross-device state).
     ``predictor`` drives prediction-guided search inside every chunk.
     ``candidates`` is an optional ``(T, lanes, topk)`` model-top-k plane
     (the serve pipeline's trial symbols): full-size chunks' rows are cut
@@ -265,12 +262,10 @@ def decode_chunked(chunks: ChunkedLanes | ContainerSlab, n_symbols: int,
                 return kops.rans_decode_chunked(
                     n_symbols=n_symbols, tbl=tbl, chunk_size=chunk_size,
                     prob_bits=prob_bits, predictor=predictor,
-                    candidates=candidates, interpret=interpret,
-                    from_container=chunks)
+                    candidates=candidates, from_container=chunks)
             return kops.rans_decode_chunked(
                 chunks, n_symbols, tbl, chunk_size, prob_bits=prob_bits,
-                predictor=predictor, candidates=candidates,
-                interpret=interpret)
+                predictor=predictor, candidates=candidates)
         if slab_in:
             chunks = bitstream.slab_to_chunked(chunks)
         return coder.decode_chunked(chunks, n_symbols, tbl, chunk_size,
@@ -297,7 +292,6 @@ def decode_chunked(chunks: ChunkedLanes | ContainerSlab, n_symbols: int,
         if backend == "kernel":
             return kops.rans_decode(enc, n, tb, prob_bits=prob_bits,
                                     predictor=predictor, candidates=cand,
-                                    interpret=interpret,
                                     exhausted_flags=flags)
         return coder.decode(enc, n, tb, prob_bits,
                             predictor=predictor, use_lut=use_lut,
@@ -324,8 +318,8 @@ def decode_chunked(chunks: ChunkedLanes | ContainerSlab, n_symbols: int,
             sym, _, cpro, cund = kops.rans_decode_chunked(
                 enc_loc, n_loc * chunk_size, tbl_flat, chunk_size,
                 prob_bits=prob_bits, predictor=predictor,
-                candidates=cand_flat, interpret=interpret,
-                chunk_probes=True, exhausted_flags=True)
+                candidates=cand_flat, chunk_probes=True,
+                exhausted_flags=True)
             sym3 = sym.reshape(lanes, n_loc, chunk_size).swapaxes(0, 1)
             per_chunk = (jnp.sum(cpro.astype(jnp.float32), axis=1)
                          / (lanes * chunk_size))
@@ -371,7 +365,7 @@ def decode_chunked(chunks: ChunkedLanes | ContainerSlab, n_symbols: int,
             in_specs=(jax.tree.map(lambda _: P("chunks"), sub),
                       _chunked_table_specs(tbl, sharded=True),
                       *extra_specs),
-            out_specs=out_specs, check_rep=False)(sub, tbl_full,
+            out_specs=out_specs, check_vma=False)(sub, tbl_full,
                                                   *extra_args)
     else:
         def body(enc_loc, tbl_rep, *cand):
@@ -383,7 +377,7 @@ def decode_chunked(chunks: ChunkedLanes | ContainerSlab, n_symbols: int,
             in_specs=(jax.tree.map(lambda _: P("chunks"), sub),
                       _chunked_table_specs(tbl, sharded=False),
                       *extra_specs),
-            out_specs=out_specs, check_rep=False)(sub, tbl, *extra_args)
+            out_specs=out_specs, check_vma=False)(sub, tbl, *extra_args)
 
     coder._check_exhausted(under_full, "parallel.decode_chunked")
     lanes = sym_full.shape[1]

@@ -121,16 +121,15 @@ def collect_tables(params, cfg: ModelConfig, tokens: jax.Array,
 
 def lm_compress(params, cfg: ModelConfig, tokens: jax.Array,
                 prob_bits: int = C.PROB_BITS,
-                backend: str = "coder",
-                interpret: bool = True) -> CompressStats:
+                backend: str = "coder") -> CompressStats:
     """tokens (lanes, T) -> multi-lane rANS bitstream + stats.
 
     ``backend="kernel"`` feeds the teacher-forced ``(T, lanes, K)`` tables
     of :func:`collect_tables` straight into the fused-compaction Pallas
     encode kernel (the adaptive per-lane layout encodes in-kernel and the
     packed stream comes straight off the kernel — no host-side
-    ``compact_records`` pass; interpret mode on CPU); ``backend="coder"``
-    runs the pure-JAX lane scan.  Both consume ``core.update``, so the
+    ``compact_records`` pass); ``backend="coder"`` runs the pure-JAX lane
+    scan.  Both consume ``core.update``, so the
     produced bitstream — including the per-lane ``overflow`` flags on the
     returned ``EncodedLanes`` — is byte-identical either way and
     round-trips through :func:`lm_decompress` bit-exactly.
@@ -140,7 +139,7 @@ def lm_compress(params, cfg: ModelConfig, tokens: jax.Array,
     if backend == "kernel":
         from repro.kernels.ops import rans_encode
         enc = rans_encode(tokens.astype(jnp.int32), tables,
-                          prob_bits=prob_bits, interpret=interpret)
+                          prob_bits=prob_bits)
     elif backend == "coder":
         enc = coder.encode(tokens.astype(jnp.int32), tables)
     else:
@@ -185,8 +184,7 @@ def _lm_decompress_scan(params, cfg: ModelConfig, enc: coder.EncodedLanes,
 
 
 def _fused_scan(params, cfg: ModelConfig, enc: coder.EncodedLanes,
-                cache, tok, t0, n: int, prob_bits: int, topk: int,
-                interpret: bool):
+                cache, tok, t0, n: int, prob_bits: int, topk: int):
     """The fused serve decode core (DESIGN.md §9): ONE traced program.
 
     A ``lax.scan`` over positions ``[t0, t0+n)`` carrying BOTH the model
@@ -195,10 +193,10 @@ def _fused_scan(params, cfg: ModelConfig, enc: coder.EncodedLanes,
     path (:func:`_step_freq_cdf` — no Barrett planes, no ``(T, lanes, K)``
     plane stacking), ranks its top-k trial symbols, and pops one symbol per
     lane with the per-step Pallas kernel
-    (``kernels.rans_decode.rans_decode_step``; interpret mode inlines the
-    kernel into this very program).  The decoded symbol feeds straight back
-    into the model — no pure-JAX reference decode runs anywhere on this
-    path, and no table plane ever round-trips through HBM.
+    (``kernels.rans_decode.rans_decode_step``; off the TPU, interpret mode
+    inlines the kernel into this very program).  The decoded symbol feeds
+    straight back into the model — no pure-JAX reference decode runs
+    anywhere on this path, and no table plane ever round-trips through HBM.
     """
     from repro.kernels.rans_decode import rans_decode_step
     dec0 = coder.decoder_init(enc)
@@ -211,7 +209,7 @@ def _fused_scan(params, cfg: ModelConfig, enc: coder.EncodedLanes,
         cands = model_topk_candidates(lg[:, :cfg.vocab_size], topk)
         s, ptr, sym, probes, u = rans_decode_step(
             buf_t, s, ptr, freq, cdf, prob_bits=prob_bits,
-            candidates=cands, interpret=interpret)
+            candidates=cands)
         carry = (cache, s, ptr, under | (u > 0),
                  sym[:, None].astype(jnp.int32))
         return carry, (sym, probes)
@@ -224,18 +222,16 @@ def _fused_scan(params, cfg: ModelConfig, enc: coder.EncodedLanes,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("cfg", "n_symbols", "prob_bits", "topk",
-                                    "interpret"))
+                   static_argnames=("cfg", "n_symbols", "prob_bits", "topk"))
 def _lm_decompress_fused(params, cfg: ModelConfig, enc: coder.EncodedLanes,
-                         n_symbols: int, prob_bits: int, topk: int,
-                         interpret: bool = True):
+                         n_symbols: int, prob_bits: int, topk: int):
     """Monolithic fused decode: whole stream in one traced program."""
     lanes = enc.buf.shape[0]
     cache = init_state(cfg, lanes, n_symbols)
     tok = jnp.full((lanes, 1), BOS, jnp.int32)
     _, _, sym, probes, under = _fused_scan(params, cfg, enc, cache, tok,
                                            jnp.int32(0), n_symbols,
-                                           prob_bits, topk, interpret)
+                                           prob_bits, topk)
     return sym, probes, under
 
 
@@ -260,7 +256,7 @@ def _fused_on_lane_mesh(params, enc, mesh, local_fn):
     ``local_fn(params, enc_local) -> (sym (lanes_loc, T), probes)`` is the
     single-device program.  Bit-exact vs the unsharded path.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     lane_axis = 0 if enc.buf.ndim == 2 else 1   # EncodedLanes|ChunkedLanes
     espec = jax.tree.map(lambda _: P(*([None] * lane_axis + ["lanes"])), enc)
@@ -269,14 +265,13 @@ def _fused_on_lane_mesh(params, enc, mesh, local_fn):
     # third output: the per-lane stream-exhaustion flag (lanes,)
     return shard_map(local_fn, mesh=mesh, in_specs=(pspec, espec),
                      out_specs=(P("lanes"), probes_spec, P("lanes")),
-                     check_rep=False)(params, enc)
+                     check_vma=False)(params, enc)
 
 
 def lm_decompress(params, cfg: ModelConfig, enc: coder.EncodedLanes,
                   n_symbols: int, prob_bits: int = C.PROB_BITS,
                   topk: int = 4, backend: str = "coder",
-                  mesh=None,
-                  interpret: bool = True, lane_probes: bool = False):
+                  mesh=None, lane_probes: bool = False):
     """Bitstream -> tokens, decoding with model-top-k speculation (T3).
 
     ``backend="coder"`` pops every symbol inside the sequential model scan
@@ -315,12 +310,12 @@ def lm_decompress(params, cfg: ModelConfig, enc: coder.EncodedLanes,
         if _lane_mesh_check(mesh, enc.buf.shape[0]):
             def local(params_l, enc_l):
                 return _lm_decompress_fused(params_l, cfg, enc_l, n_symbols,
-                                            prob_bits, topk, interpret)
+                                            prob_bits, topk)
             sym, probes, under = _fused_on_lane_mesh(params, enc, mesh,
                                                      local)
         else:
             sym, probes, under = _lm_decompress_fused(
-                params, cfg, enc, n_symbols, prob_bits, topk, interpret)
+                params, cfg, enc, n_symbols, prob_bits, topk)
         coder._check_exhausted(under, "lm_decompress")
         out = (sym, jnp.mean(probes.astype(jnp.float32)))
         if lane_probes:
@@ -336,7 +331,7 @@ def lm_decompress(params, cfg: ModelConfig, enc: coder.EncodedLanes,
                                                  collect_planes=True)
     sym, avg, per_lane = rans_decode(enc, n_symbols, tables,
                                      prob_bits=prob_bits, candidates=cands,
-                                     interpret=interpret, lane_probes=True)
+                                     lane_probes=True)
     if lane_probes:
         return sym, avg, per_lane
     return sym, avg
@@ -361,8 +356,7 @@ class ChunkedCompressStats(NamedTuple):
 def lm_compress_chunked(params, cfg: ModelConfig, tokens: jax.Array,
                         chunk_size: int, prob_bits: int = C.PROB_BITS,
                         mesh=None, backend: str = "coder",
-                        cap: int | None = None,
-                        interpret: bool = True) -> ChunkedCompressStats:
+                        cap: int | None = None) -> ChunkedCompressStats:
     """tokens (lanes, T) -> chunked multi-lane bitstream + stats.
 
     Tables still come from one teacher-forced pass (the model cache spans
@@ -379,8 +373,7 @@ def lm_compress_chunked(params, cfg: ModelConfig, tokens: jax.Array,
     lanes, t_len = tokens.shape
     tables, xent_bits = collect_tables(params, cfg, tokens, prob_bits)
     chunks = encode_chunked(tokens.astype(jnp.int32), tables, chunk_size,
-                            mesh=mesh, backend=backend, cap=cap,
-                            interpret=interpret)
+                            mesh=mesh, backend=backend, cap=cap)
     bits = (jnp.sum(chunks.length.astype(jnp.float32)) * 8.0
             / (lanes * t_len))
     return ChunkedCompressStats(chunks=chunks, chunk_size=chunk_size,
@@ -421,21 +414,19 @@ def _lm_decompress_chunk(params, cfg: ModelConfig, enc: coder.EncodedLanes,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("cfg", "n", "prob_bits", "topk",
-                                    "interpret"))
+                   static_argnames=("cfg", "n", "prob_bits", "topk"))
 def _lm_decompress_fused_chunk(params, cfg: ModelConfig,
                                enc: coder.EncodedLanes, cache, tok, t0,
-                               n: int, prob_bits: int, topk: int,
-                               interpret: bool = True):
+                               n: int, prob_bits: int, topk: int):
     """Fused decode of one chunk (positions [t0, t0+n)), carried cache."""
     return _fused_scan(params, cfg, enc, cache, tok, t0, n, prob_bits,
-                       topk, interpret)
+                       topk)
 
 
 def _fused_chunked_local(params, cfg: ModelConfig,
                          chunks: "coder.ChunkedLanes | bitstream.ContainerSlab",
                          n_symbols: int, chunk_size: int, prob_bits: int,
-                         topk: int, interpret: bool):
+                         topk: int):
     """Fused chunked decode over (this device's slab of) the lane axis.
 
     The rANS state re-initializes per chunk (standalone streams); the model
@@ -460,7 +451,7 @@ def _fused_chunked_local(params, cfg: ModelConfig,
                else coder.chunk_encoded(chunks, c))
         cache, tok, sym, probes, und = _lm_decompress_fused_chunk(
             params, cfg, enc, cache, tok, jnp.int32(c * chunk_size), n=n,
-            prob_bits=prob_bits, topk=topk, interpret=interpret)
+            prob_bits=prob_bits, topk=topk)
         outs.append(sym)
         lane_sum = lane_sum + jnp.sum(probes, axis=0)
         under = under | und
@@ -473,7 +464,6 @@ def lm_decompress_chunked(params, cfg: ModelConfig,
                           chunk_size: int, prob_bits: int = C.PROB_BITS,
                           topk: int = 4, backend: str = "coder",
                           mesh=None,
-                          interpret: bool = True,
                           lane_probes: bool = False):
     """Chunked bitstream -> tokens (bit-exact inverse of lm_compress_chunked).
 
@@ -547,13 +537,13 @@ def lm_decompress_chunked(params, cfg: ModelConfig,
             def local(params_l, chunks_l):
                 return _fused_chunked_local(params_l, cfg, chunks_l,
                                             n_symbols, chunk_size,
-                                            prob_bits, topk, interpret)
+                                            prob_bits, topk)
             sym, lane_sum, under = _fused_on_lane_mesh(params, chunks, mesh,
                                                        local)
         else:
             sym, lane_sum, under = _fused_chunked_local(
                 params, cfg, chunks, n_symbols, chunk_size, prob_bits,
-                topk, interpret)
+                topk)
         coder._check_exhausted(under, "lm_decompress_chunked")
         out = (sym, jnp.sum(lane_sum.astype(jnp.float32))
                / (lanes * n_symbols))
@@ -594,19 +584,19 @@ def lm_decompress_chunked(params, cfg: ModelConfig,
             from repro.parallel.chunked import decode_chunked as pdecode
             return pdecode(chunks, n_symbols, tables, chunk_size, mesh=mesh,
                            prob_bits=prob_bits, backend="kernel",
-                           candidates=cands, interpret=interpret)
+                           candidates=cands)
         from repro.kernels.ops import rans_decode_chunked
         if slab_in:
             # pass 2 zero-copy: the kernel DMAs each (chunk, lane) window
             # out of the packed slab — no dense stream rebuild at all
             sym, avg, per_lane = rans_decode_chunked(
                 n_symbols=n_symbols, tbl=tables, chunk_size=chunk_size,
-                prob_bits=prob_bits, candidates=cands, interpret=interpret,
+                prob_bits=prob_bits, candidates=cands,
                 lane_probes=True, from_container=chunks)
         else:
             sym, avg, per_lane = rans_decode_chunked(
                 chunks, n_symbols, tables, chunk_size, prob_bits=prob_bits,
-                candidates=cands, interpret=interpret, lane_probes=True)
+                candidates=cands, lane_probes=True)
         if lane_probes:
             return sym, avg, per_lane
         return sym, avg
@@ -634,20 +624,20 @@ def histogram_compress(symbols: np.ndarray, k: int,
 
 def histogram_decompress(enc: coder.EncodedLanes, n_symbols: int, tbl,
                          prob_bits: int = C.PROB_BITS, predictor=None,
-                         backend: str = "kernel", interpret: bool = True):
+                         backend: str = "kernel"):
     """Static-table decode — through the Pallas kernel by default.
 
     The serving counterpart of :func:`histogram_compress`: both backends
     consume ``core.search``, so symbols and probe telemetry are identical
-    whether the decode ran in-kernel (``backend="kernel"``, interpret mode
-    on CPU) or in the pure-JAX lane coder (``backend="coder"``).
+    whether the decode ran in-kernel (``backend="kernel"``) or in the
+    pure-JAX lane coder (``backend="coder"``).
     ``predictor`` enables prediction-guided search (e.g. the paper's
     ``NeighborAverage`` for image rows).  Returns (symbols, avg_probes).
     """
     if backend == "kernel":
         from repro.kernels.ops import rans_decode
         return rans_decode(enc, n_symbols, tbl, prob_bits=prob_bits,
-                           predictor=predictor, interpret=interpret)
+                           predictor=predictor)
     if backend == "coder":
         return coder.decode(enc, n_symbols, tbl, prob_bits,
                             predictor=predictor)

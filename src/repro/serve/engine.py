@@ -200,8 +200,7 @@ def _row_reset(mask, a):
 
 
 def _chunk_body(params, cache, tok, fresh, pos0, mode, n_valid, tf, buf,
-                start, *, cfg, chunk_size, prob_bits, topk, backend,
-                interpret):
+                start, *, cfg, chunk_size, prob_bits, topk, backend):
     """One continuous-batching cycle: ``chunk_size`` steps over all rows.
 
     Rows are the flattened ``slots * lanes`` batch axis; all per-slot
@@ -262,7 +261,7 @@ def _chunk_body(params, cache, tok, fresh, pos0, mode, n_valid, tf, buf,
         cands = model_topk_candidates(lg[:, :cfg.vocab_size], topk)
         s2, p2, sym, probes, u = rans_decode_step_rows(
             buf_t, s, ptr, tbl, prob_bits=prob_bits, candidates=cands,
-            backend=backend, interpret=interpret)
+            backend=backend)
         s = jnp.where(active, s2, s)
         ptr = jnp.where(active, p2, ptr)
         und = (active & (u > 0)).astype(jnp.int32)
@@ -277,8 +276,7 @@ def _chunk_body(params, cache, tok, fresh, pos0, mode, n_valid, tf, buf,
 
 
 def _prefill_body(params, cache, tok, fresh, pos0, mode, n_valid, tf, buf,
-                  start, *, cfg, chunk_size, prob_bits, topk, backend,
-                  interpret):
+                  start, *, cfg, chunk_size, prob_bits, topk, backend):
     """All-compress fast cycle: same signature and outputs as
     :func:`_chunk_body`, but ONE teacher-forced block pass over the chunk
     instead of ``chunk_size`` sequential decode steps (the serving-engine
@@ -295,7 +293,7 @@ def _prefill_body(params, cache, tok, fresh, pos0, mode, n_valid, tf, buf,
     zeros — finalize never reads them for compress rows.
     """
     from repro.serve.compress import step_tables
-    del mode, buf, start, topk, backend, interpret  # step-path-only inputs
+    del mode, buf, start, topk, backend  # step-path-only inputs
     cache = jax.tree.map(functools.partial(_row_reset, fresh), cache)
     tok = jnp.where(fresh[:, None], jnp.int32(BOS), tok)
     # the step body feeds the PREVIOUS token at each step: inputs are the
@@ -319,15 +317,14 @@ def _encode_rows(symbols, tbl, cap: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _compiled_program(body, cfg, chunk_size, prob_bits, topk, backend,
-                      interpret):
+def _compiled_program(body, cfg, chunk_size, prob_bits, topk, backend):
     """Process-wide program cache: engines with the same traced geometry
     share ONE jitted executable per cycle body (a fresh ``jax.jit``
     wrapper per engine would recompile per instance — the retrace the
     engine exists to avoid)."""
     return jax.jit(functools.partial(
         body, cfg=cfg, chunk_size=chunk_size, prob_bits=prob_bits,
-        topk=topk, backend=backend, interpret=interpret))
+        topk=topk, backend=backend))
 
 
 class BatchEngine:
@@ -391,7 +388,7 @@ class BatchEngine:
                  max_len: int | None = None, cap: int | None = None,
                  prob_bits: int = C.PROB_BITS, topk: int = 4,
                  max_queue: int = 64, step_backend: str = "coder",
-                 mesh=None, interpret: bool = True,
+                 mesh=None,
                  prefill: str = "auto"):
         if step_backend not in ("coder", "kernel"):
             raise ValueError(f"unknown step backend {step_backend!r}")
@@ -420,7 +417,6 @@ class BatchEngine:
         self.topk = topk
         self.max_queue = max_queue
         self.step_backend = step_backend
-        self.interpret = interpret
         # state-spec-driven geometry: what the shared state actually is
         # (ring vs recurrent), how many ring slots init_state allocated,
         # and past which length a request's conditioning would diverge
@@ -448,13 +444,13 @@ class BatchEngine:
                                 what="batched engine (its slots x lanes rows)"):
             return _compiled_program(
                 body, self.cfg, self.chunk_size, self.prob_bits, self.topk,
-                self.step_backend, self.interpret)
-        from jax.experimental.shard_map import shard_map
+                self.step_backend)
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         core = functools.partial(
             body, cfg=self.cfg, chunk_size=self.chunk_size,
             prob_bits=self.prob_bits, topk=self.topk,
-            backend=self.step_backend, interpret=self.interpret)
+            backend=self.step_backend)
         rows, rows2 = P("lanes"), P("lanes", None)
         # arbitrary state pytrees shard by the protocol's row-axis pin
         # (axis 1 on every leaf — ring or recurrent alike)
@@ -466,7 +462,7 @@ class BatchEngine:
                       rows2, rows2, rows),
             out_specs=(carry, rows2, P(None, "lanes"), P(None, "lanes"),
                        P(None, "lanes"), P(None, "lanes")),
-            check_rep=False)
+            check_vma=False)
         return jax.jit(core)
 
     # -- admission --------------------------------------------------------

@@ -1,11 +1,13 @@
 """Shared test substrate: hermetic CPU JAX + persistent compilation cache.
 
-Importing this before any test module guarantees every suite runs on the
-CPU backend (the container has no accelerator) and that XLA executables
-persist across pytest sessions under ``.pytest_cache/jax`` — the suite's
-wall time is dominated by recompilation, so warm runs are several times
-faster.  Session-scoped fixtures below hold the TableSet/symbol cases that
-many tests used to rebuild per-test.
+Importing this before any test module pins the CPU backend unless
+``JAX_PLATFORMS`` is set (so the real-hardware tier can run on a TPU host),
+and places the persistent compilation cache by the repo's one rule
+(``repro.launch.compile_cache``) — the suite's wall time is dominated by
+recompilation, so warm runs are several times faster.  Off the TPU every
+Pallas kernel runs in interpret mode (``kernels.common.interpret_mode``).
+Session-scoped fixtures below hold the TableSet/symbol cases that many
+tests used to rebuild per-test.
 """
 
 from __future__ import annotations
@@ -17,20 +19,15 @@ import pytest
 
 import jax
 
-# hermetic default: pin CPU (the container has no accelerator).  An explicit
-# JAX_PLATFORMS in the environment wins, so the real-hardware kernel tier
-# can run on a TPU host: JAX_PLATFORMS=tpu pytest tests/test_tpu_hw.py -m tpu
-if not os.environ.get("JAX_PLATFORMS"):
+# the one place tests pin the CPU: JAX_PLATFORMS in the environment wins,
+# even empty (JAX's own default backend), so the real-hardware kernel tier
+# can run on a TPU host: JAX_PLATFORMS= pytest tests/test_tpu_hw.py -m tpu
+if "JAX_PLATFORMS" not in os.environ:
     jax.config.update("jax_platforms", "cpu")
 
-_CACHE_DIR = os.path.join(os.path.dirname(os.path.dirname(__file__)),
-                          ".pytest_cache", "jax")
-try:  # persistent XLA compilation cache (saves minutes on warm runs)
-    jax.config.update("jax_compilation_cache_dir", _CACHE_DIR)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-except Exception:  # older jax: cache knobs absent — correctness unaffected
-    pass
+from repro.launch.compile_cache import setup_compile_cache  # noqa: E402
+
+setup_compile_cache()
 
 import jax.numpy as jnp  # noqa: E402  (after backend pinning)
 

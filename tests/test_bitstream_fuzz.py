@@ -27,8 +27,6 @@ import pytest
 from _prop import ints, sweep
 from repro.core import bitstream, coder, spc
 
-jax.config.update("jax_platforms", "cpu")
-
 pytestmark = pytest.mark.fuzz
 
 

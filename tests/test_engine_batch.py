@@ -26,8 +26,6 @@ from repro.serve.compress import lm_compress_chunked, lm_decompress_chunked
 from repro.serve.engine import (BatchEngine, EngineQueueFullError,
                                 RequestOverflowError)
 
-jax.config.update("jax_platforms", "cpu")
-
 CFG = get_smoke_config("ras-pimc")
 KEY = jax.random.PRNGKey(2)
 LANES = 4

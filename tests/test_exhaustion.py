@@ -29,8 +29,6 @@ from repro.core import bitstream, coder, spc
 from repro.core.coder import StreamExhaustedError
 from repro.kernels import ops as kops
 
-jax.config.update("jax_platforms", "cpu")
-
 LANES = 4
 
 

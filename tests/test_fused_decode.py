@@ -27,8 +27,6 @@ from repro.data.pipeline import token_stream
 from repro.kernels.rans_decode import rans_decode_step
 from repro.models import init_model
 
-jax.config.update("jax_platforms", "cpu")
-
 _GEN_PATH = os.path.join(os.path.dirname(__file__), "golden_vectors",
                          "generate.py")
 _spec = importlib.util.spec_from_file_location("golden_generate", _GEN_PATH)

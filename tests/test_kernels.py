@@ -1,4 +1,5 @@
-"""Per-kernel validation: Pallas (interpret=True) vs pure-jnp ref oracles.
+"""Per-kernel validation: Pallas (interpret mode off the TPU) vs pure-jnp
+ref oracles.
 
 Sweeps shapes/dtypes per the kernel-testing contract and asserts exact
 integer equality (rANS is bit-exact — allclose degenerates to equality).
@@ -12,8 +13,6 @@ import pytest
 from repro.core import coder, constants as C, spc
 from repro.core.predictors import NeighborAverage
 from repro.kernels import ops, ref
-
-jax.config.update("jax_platforms", "cpu")
 
 
 def _case(seed, k, lanes, t, conc=0.5, dtype=jnp.float32):

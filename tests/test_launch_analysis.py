@@ -1,14 +1,13 @@
 """Launch/analysis substrate tests (no 512-device init — that is dryrun-only)."""
 
+from pathlib import Path
+
 import numpy as np
-import jax
 import pytest
 
 from repro.analysis.hlo import collective_stats, op_histogram, _shape_bytes
 from repro.analysis.roofline import model_flops, scan_multiplier
 from repro.configs import ARCH_IDS, SHAPES, get_config, grid, shape_applicable
-
-jax.config.update("jax_platforms", "cpu")
 
 
 def test_grid_covers_40_cells():
@@ -101,3 +100,25 @@ def test_mesh_helpers():
     # on 1 CPU device only shape (1,1) is constructible
     m = make_mesh_for(1)
     assert m.devices.size == 1
+
+
+def test_compile_cache_rule(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR, when set, decides alone; otherwise every
+    entry point shares one fixed directory inside the checkout."""
+    import jax
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.setup_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        fixed = compile_cache.setup_compile_cache()
+        assert fixed == str(compile_cache.CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == fixed
+        # inside the checkout: the repo root holds tests/ and the cache
+        assert compile_cache.CACHE_DIR.parent == (
+            Path(__file__).resolve().parents[1])
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

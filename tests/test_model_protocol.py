@@ -44,8 +44,6 @@ from repro.models import (PrefillUnsupportedError, abstract_model,
 from repro.serve.compress import lm_compress_chunked, lm_decompress_chunked
 from repro.serve.engine import BatchEngine, _chunk_body
 
-jax.config.update("jax_platforms", "cpu")
-
 CHUNK = 8
 
 
@@ -225,7 +223,7 @@ def test_frozen_rows_keep_recurrent_state(zoo):
     cfg, params = zoo["mamba2-130m"]
     rows = 2
     kw = dict(cfg=cfg, chunk_size=CHUNK, prob_bits=12, topk=4,
-              backend="coder", interpret=True)
+              backend="coder")
 
     def run(tf):
         cache = init_state(cfg, rows, 16)

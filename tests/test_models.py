@@ -14,8 +14,6 @@ from repro.models.moe import make_moe_defs, moe_capacity, moe_dense
 from repro.models.param import init_params
 from repro.models.ssm import ssd_chunked, ssd_sequential
 
-jax.config.update("jax_platforms", "cpu")
-
 KEY = jax.random.PRNGKey(0)
 
 # frontier-scale archs: their smoke configs still dominate suite wall time,

@@ -26,8 +26,6 @@ from repro.core.predictors import LastValue, NeighborAverage, ZeroPredictor
 from repro.data.pipeline import candidate_planes
 from repro.kernels import ops, rans_decode, ref
 
-jax.config.update("jax_platforms", "cpu")
-
 
 def _candidate_planes(syms, k, topk, hit_rate, seed):
     """jnp view of the shared model-top-k plane synthesizer (the benchmark

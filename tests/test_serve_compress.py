@@ -13,8 +13,6 @@ from repro.serve.compress import (histogram_compress, histogram_decompress,
                                   lm_compress, lm_decompress)
 from repro.serve.engine import generate, prefill
 
-jax.config.update("jax_platforms", "cpu")
-
 CFG = get_smoke_config("ras-pimc")
 KEY = jax.random.PRNGKey(1)
 

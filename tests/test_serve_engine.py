@@ -21,8 +21,6 @@ from repro.models import init_model
 from repro.models.transformer import decode_step, init_cache
 from repro.serve.engine import generate, prefill, teacher_forced_scan
 
-jax.config.update("jax_platforms", "cpu")
-
 CFG = get_smoke_config("ras-pimc")
 KEY = jax.random.PRNGKey(2)
 

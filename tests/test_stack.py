@@ -23,8 +23,6 @@ import pytest
 
 from repro.core import coder, constants as C, spc, stack
 
-jax.config.update("jax_platforms", "cpu")
-
 LANES, CAP = 4, 512
 
 

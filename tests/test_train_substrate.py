@@ -20,8 +20,6 @@ from repro.train.optimizer import (adamw_init, adamw_update,
                                    global_norm)
 from repro.train.train_loop import init_train_state, make_train_step
 
-jax.config.update("jax_platforms", "cpu")
-
 CFG = get_smoke_config("ras-pimc").with_(grad_accum=1)
 KEY = jax.random.PRNGKey(0)
 

@@ -37,8 +37,6 @@ from repro.core import bitstream, coder, constants as C, spc, update
 from repro.kernels import common as kcommon
 from repro.kernels import ops, rans_encode, ref
 
-jax.config.update("jax_platforms", "cpu")
-
 
 def _assert_streams_equal(got, want):
     for g, w in zip(got, want):

@@ -32,8 +32,6 @@ import pytest
 from repro.core import bitstream, coder, spc
 from repro.kernels import ops
 
-jax.config.update("jax_platforms", "cpu")
-
 _GEN_PATH = os.path.join(os.path.dirname(__file__), "golden_vectors",
                          "generate.py")
 _spec = importlib.util.spec_from_file_location("golden_generate_zc",
