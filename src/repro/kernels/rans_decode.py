@@ -676,6 +676,7 @@ def rans_decode_step(buf: jax.Array,    # (cap, lanes) uint8, lane-minor
             jax.ShapeDtypeStruct((1, lanes), _I32),
         ],
         interpret=common.interpret_mode(),
+        name="rans_decode_step",
     )(buf, s.reshape(1, lanes), ptr.astype(_I32).reshape(1, lanes),
       freq_in, cdf_in, *extra_in)
     return s2[0], ptr2[0], sym[0], probes[0], under[0]

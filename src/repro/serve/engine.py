@@ -34,6 +34,7 @@ import numpy as np
 
 from repro.core import bitstream, coder, constants as C
 from repro.core.predictors import model_topk_candidates
+from repro.serve.trace import EngineTrace, hlo_scopes
 from repro.models import (ModelConfig, PrefillUnsupportedError, can_prefill,
                           decode_step, init_state, prefill_chunk,
                           recurrent_state_tree, ring_length, state_spec,
@@ -140,6 +141,8 @@ def generate(params, cfg: ModelConfig, prompt: jax.Array, n_new: int,
 
 BOS = 0
 MODE_IDLE, MODE_COMPRESS, MODE_DECOMPRESS = 0, 1, 2
+# jax.named_scope names of the cycle programs' layers (BatchEngine.scope_maps)
+SCOPES = ("model", "spc", "topk", "decode_kernel")
 
 
 class EngineQueueFullError(RuntimeError):
@@ -236,8 +239,9 @@ def _chunk_body(params, cache, tok, fresh, pos0, mode, n_valid, tf, buf,
     """
     from repro.kernels.ops import rans_decode_step_rows
     from repro.serve.compress import step_tables
-    cache = jax.tree.map(functools.partial(_row_reset, fresh), cache)
-    tok = jnp.where(fresh[:, None], jnp.int32(BOS), tok)
+    with jax.named_scope("model"):
+        cache = jax.tree.map(functools.partial(_row_reset, fresh), cache)
+        tok = jnp.where(fresh[:, None], jnp.int32(BOS), tok)
     rec_tree = recurrent_state_tree(cache)      # static (trace-time) bools
     dec0 = coder.decoder_init(coder.EncodedLanes(
         buf=buf, start=start, length=jnp.zeros_like(start), overflow=None))
@@ -255,13 +259,17 @@ def _chunk_body(params, cache, tok, fresh, pos0, mode, n_valid, tf, buf,
         cache, s, ptr, tok = carry
         active = t < n_valid
         pos = pos0 + jnp.minimum(t, n_valid)
-        lg, new_cache = decode_step(params, cache, tok, pos, cfg)
-        cache = _freeze(active, new_cache, cache)
-        tbl = step_tables(lg, cfg.vocab_size, prob_bits)
-        cands = model_topk_candidates(lg[:, :cfg.vocab_size], topk)
-        s2, p2, sym, probes, u = rans_decode_step_rows(
-            buf_t, s, ptr, tbl, prob_bits=prob_bits, candidates=cands,
-            backend=backend)
+        with jax.named_scope("model"):
+            lg, new_cache = decode_step(params, cache, tok, pos, cfg)
+            cache = _freeze(active, new_cache, cache)
+        with jax.named_scope("spc"):
+            tbl = step_tables(lg, cfg.vocab_size, prob_bits)
+        with jax.named_scope("topk"):
+            cands = model_topk_candidates(lg[:, :cfg.vocab_size], topk)
+        with jax.named_scope("decode_kernel"):
+            s2, p2, sym, probes, u = rans_decode_step_rows(
+                buf_t, s, ptr, tbl, prob_bits=prob_bits, candidates=cands,
+                backend=backend)
         s = jnp.where(active, s2, s)
         ptr = jnp.where(active, p2, ptr)
         und = (active & (u > 0)).astype(jnp.int32)
@@ -294,15 +302,19 @@ def _prefill_body(params, cache, tok, fresh, pos0, mode, n_valid, tf, buf,
     """
     from repro.serve.compress import step_tables
     del mode, buf, start, topk, backend  # step-path-only inputs
-    cache = jax.tree.map(functools.partial(_row_reset, fresh), cache)
-    tok = jnp.where(fresh[:, None], jnp.int32(BOS), tok)
-    # the step body feeds the PREVIOUS token at each step: inputs are the
-    # carried token (BOS if fresh) followed by all but the last tf token
-    inputs = jnp.concatenate([tok, tf[:, :chunk_size - 1]], axis=1)
-    lgs, cache = prefill_chunk(params, cache, inputs, pos0, n_valid, cfg)
-    tables = jax.lax.map(
-        lambda lg: step_tables(lg, cfg.vocab_size, prob_bits),
-        jnp.moveaxis(lgs, 1, 0))
+    with jax.named_scope("model"):
+        cache = jax.tree.map(functools.partial(_row_reset, fresh), cache)
+        tok = jnp.where(fresh[:, None], jnp.int32(BOS), tok)
+        # the step body feeds the PREVIOUS token at each step: inputs are
+        # the carried token (BOS if fresh) followed by all but the last tf
+        # token
+        inputs = jnp.concatenate([tok, tf[:, :chunk_size - 1]], axis=1)
+        lgs, cache = prefill_chunk(params, cache, inputs, pos0, n_valid,
+                                   cfg)
+    with jax.named_scope("spc"):
+        tables = jax.lax.map(
+            lambda lg: step_tables(lg, cfg.vocab_size, prob_bits),
+            jnp.moveaxis(lgs, 1, 0))
     idx = jnp.clip(n_valid - 1, 0, chunk_size - 1)
     last = jnp.take_along_axis(tf, idx[:, None], axis=1)
     tok = jnp.where((n_valid > 0)[:, None], last, tok)
@@ -381,6 +393,12 @@ class BatchEngine:
     construction when the family cannot prefill — the named-error guard
     against silently assuming attention state for recurrent families.
     ``prefill_cycles`` counts fast-path dispatches.
+
+    ``trace`` (:class:`repro.serve.trace.EngineTrace`) records the engine's
+    host spans, its counters (``cycles.step``/``.prefill``,
+    ``requests.admitted``/``.retired``/``.failed``/``.rejected``) and the
+    last admissions ``(rid, slot, cycle)``; :meth:`scope_maps` names the
+    layer (:data:`SCOPES`) of each instruction of the cycle programs.
     """
 
     def __init__(self, params, cfg: ModelConfig, *, slots: int = 4,
@@ -429,8 +447,13 @@ class BatchEngine:
         self._slots: list[_Req | None] = [None] * slots
         self._queue: list[_Req] = []
         self._next_rid = 0
-        self.admission_log: list[tuple[int, int, int]] = []  # (rid, slot, cycle)
+        self.trace = EngineTrace()
         self.prefill_cycles = 0      # cycles served by the prefill program
+        # program -> (jitted, argument shapes) at its first launch, and the
+        # scope maps built from them (scope_maps)
+        self._launched: dict[str, tuple] = {}
+        self._scope_maps: dict[str, dict[str, str | None]] = {}
+        self._wall = False           # run(clock="wall") is running
         self._prog = self._build_program(mesh)
         self._prog_prefill = (self._build_program(mesh, body=_prefill_body)
                               if prefill in ("auto", "force")
@@ -465,10 +488,30 @@ class BatchEngine:
             check_vma=False)
         return jax.jit(core)
 
+    def scope_maps(self) -> dict[str, dict[str, str | None]]:
+        """``{program: {HLO instruction: scope}}`` for each cycle program
+        launched so far (``"step"``, ``"prefill"``); a scope is one of
+        :data:`SCOPES`, or None for what no scope names.
+
+        Built on the first call per program by compiling the kept jitted
+        program again at the shapes of its first launch (a hit where the
+        persistent compilation cache is on) and reading its optimized HLO,
+        whose instruction names are the names the profiler gives the
+        program's device operations.  Needs neither the engine's params nor
+        its program attributes, so it works after they are dropped.  It
+        compiles: call it outside any timed window.
+        """
+        for name, (prog, shapes) in self._launched.items():
+            if name not in self._scope_maps:
+                text = prog.lower(*shapes).compile().as_text()
+                self._scope_maps[name] = hlo_scopes(text, SCOPES)
+        return dict(self._scope_maps)
+
     # -- admission --------------------------------------------------------
 
     def _submit(self, req: _Req) -> int:
         if len(self._queue) >= self.max_queue:
+            self.trace.count("requests.rejected")
             raise EngineQueueFullError(
                 f"engine admission queue is full ({self.max_queue} waiting "
                 "requests): drain with run() or raise max_queue — rejecting "
@@ -531,7 +574,8 @@ class BatchEngine:
         engine's traced geometry: same ``lanes``, ``chunk_size`` and
         ``prob_bits``, every cell within the engine's stream window.
         """
-        slab = bitstream.parse_chunked(blob)
+        with self.trace.span("engine.submit.parse"):
+            slab = bitstream.parse_chunked(blob)
         meta = slab.meta
         if meta.lanes != self.lanes:
             raise ValueError(
@@ -564,17 +608,20 @@ class BatchEngine:
     # -- one scheduling cycle --------------------------------------------
 
     def _admit(self, now: float, cycle: int):
-        self._queue.sort(key=lambda r: (r.arrival, r.rid))
-        for s in range(self.slots):
-            if self._slots[s] is not None:
-                continue
-            pick = next((r for r in self._queue if r.arrival <= now), None)
-            if pick is None:
-                break
-            self._queue.remove(pick)
-            pick.slot, pick.admitted_at = s, now
-            self._slots[s] = pick
-            self.admission_log.append((pick.rid, s, cycle))
+        with self.trace.span("engine.admit"):
+            self._queue.sort(key=lambda r: (r.arrival, r.rid))
+            for s in range(self.slots):
+                if self._slots[s] is not None:
+                    continue
+                pick = next((r for r in self._queue if r.arrival <= now),
+                            None)
+                if pick is None:
+                    break
+                self._queue.remove(pick)
+                pick.slot, pick.admitted_at = s, now
+                self._slots[s] = pick
+                self.trace.admissions.append((pick.rid, s, cycle))
+                self.trace.count("requests.admitted")
 
     def _build_cycle(self):
         """Host half of a cycle: rows-form inputs for every live slot.
@@ -585,68 +632,71 @@ class BatchEngine:
         byte copy on the path).  Returns (spec, arrays) or None when no
         slot has steps to run.
         """
-        B, S, cap = self.rows, self.chunk_size, self.cap
-        fresh = np.zeros(B, bool)
-        pos0 = np.zeros(B, np.int32)
-        mode = np.zeros(B, np.int32)
-        n_valid = np.zeros(B, np.int32)
-        tf = np.zeros((B, S), np.int32)
-        buf = np.zeros((B, cap), np.uint8)
-        start = np.zeros(B, np.int32)
-        spec = []
-        prefillable = self._prog_prefill is not None
-        for s, req in enumerate(self._slots):
-            if req is None or req.pos >= req.n_symbols:
-                continue
-            # decompress rows feed decoded symbols back step to step, and
-            # rows wrapping the ALLOCATED ring (ring_len = min(max_len,
-            # window), not max_len) overwrite slots still visible to
-            # in-chunk queries — both force the cycle onto the step
-            # program (attn_prefill requires pos0 + S <= ring slots)
-            if req.kind != "compress" or req.n_symbols > self.ring_len:
-                prefillable = False
-            r0, r1 = s * self.lanes, (s + 1) * self.lanes
-            n_c = min(S, req.n_symbols - req.pos)
-            c = req.pos // S
-            fresh[r0:r1] = req.pos == 0
-            pos0[r0:r1] = req.pos
-            n_valid[r0:r1] = n_c
-            if req.kind == "compress":
-                mode[r0:r1] = MODE_COMPRESS
-                tf[r0:r1, :n_c] = req.tokens[:, req.pos:req.pos + n_c]
-            else:
-                mode[r0:r1] = MODE_DECOMPRESS
-                slab = req.slab
-                payload = np.asarray(slab.slab, np.uint8)
-                off = np.asarray(slab.offset)
-                ln = np.asarray(slab.length)
-                for l in range(self.lanes):
-                    o, n = int(off[c, l]), int(ln[c, l])
-                    buf[r0 + l, cap - n:] = payload[o:o + n]
-                    start[r0 + l] = cap - n
-            spec.append((req.rid, s, c, n_c,
-                         req.pos + n_c >= req.n_symbols))
-            req.pos += n_c
-        if not spec:
-            return None
-        return spec, prefillable, (jnp.asarray(fresh), jnp.asarray(pos0),
-                                   jnp.asarray(mode), jnp.asarray(n_valid),
-                                   jnp.asarray(tf), jnp.asarray(buf),
-                                   jnp.asarray(start))
+        with self.trace.span("engine.build"):
+            B, S, cap = self.rows, self.chunk_size, self.cap
+            fresh = np.zeros(B, bool)
+            pos0 = np.zeros(B, np.int32)
+            mode = np.zeros(B, np.int32)
+            n_valid = np.zeros(B, np.int32)
+            tf = np.zeros((B, S), np.int32)
+            buf = np.zeros((B, cap), np.uint8)
+            start = np.zeros(B, np.int32)
+            spec = []
+            prefillable = self._prog_prefill is not None
+            for s, req in enumerate(self._slots):
+                if req is None or req.pos >= req.n_symbols:
+                    continue
+                # decompress rows feed decoded symbols back step to step, and
+                # rows wrapping the ALLOCATED ring (ring_len = min(max_len,
+                # window), not max_len) overwrite slots still visible to
+                # in-chunk queries — both force the cycle onto the step
+                # program (attn_prefill requires pos0 + S <= ring slots)
+                if req.kind != "compress" or req.n_symbols > self.ring_len:
+                    prefillable = False
+                r0, r1 = s * self.lanes, (s + 1) * self.lanes
+                n_c = min(S, req.n_symbols - req.pos)
+                c = req.pos // S
+                fresh[r0:r1] = req.pos == 0
+                pos0[r0:r1] = req.pos
+                n_valid[r0:r1] = n_c
+                if req.kind == "compress":
+                    mode[r0:r1] = MODE_COMPRESS
+                    tf[r0:r1, :n_c] = req.tokens[:, req.pos:req.pos + n_c]
+                else:
+                    mode[r0:r1] = MODE_DECOMPRESS
+                    slab = req.slab
+                    payload = np.asarray(slab.slab, np.uint8)
+                    off = np.asarray(slab.offset)
+                    ln = np.asarray(slab.length)
+                    for l in range(self.lanes):
+                        o, n = int(off[c, l]), int(ln[c, l])
+                        buf[r0 + l, cap - n:] = payload[o:o + n]
+                        start[r0 + l] = cap - n
+                spec.append((req.rid, s, c, n_c,
+                             req.pos + n_c >= req.n_symbols))
+                req.pos += n_c
+            if not spec:
+                return None
+            return spec, prefillable, (jnp.asarray(fresh), jnp.asarray(pos0),
+                                       jnp.asarray(mode), jnp.asarray(n_valid),
+                                       jnp.asarray(tf), jnp.asarray(buf),
+                                       jnp.asarray(start))
 
     def _launch(self, built):
         """Device half: dispatch the cycle program asynchronously — the
         prefill fast path when every live slot is an unwrapped compress
         request, the step program otherwise."""
-        spec, prefillable, (fresh, pos0, mode, n_valid, tf, buf,
-                           start) = built
-        prog = self._prog
+        spec, prefillable, arrays = built
+        name, prog = (("prefill", self._prog_prefill) if prefillable
+                      else ("step", self._prog))
+        args = (self.params, self._cache, self._tok) + tuple(arrays)
+        if name not in self._launched:
+            self._launched[name] = (prog, jax.tree.map(_shape_of, args))
+        with self.trace.span(f"engine.launch.{name}"):
+            self._cache, self._tok, tables, syms, probes, unders = prog(*args)
         if prefillable:
-            prog = self._prog_prefill
             self.prefill_cycles += 1
-        self._cache, self._tok, tables, syms, probes, unders = prog(
-            self.params, self._cache, self._tok, fresh, pos0, mode,
-            n_valid, tf, buf, start)
+        self.trace.count(f"cycles.{name}")
         return spec, tables, syms, probes, unders
 
     def _finalize(self, inflight, now: float, results: dict):
@@ -657,63 +707,91 @@ class BatchEngine:
         its request with :class:`RequestOverflowError`; the slot frees, the
         (at most one) already-dispatched follow-up chunk of the failed
         request is discarded at its own finalize, and no other row is
-        touched.
+        touched.  Its span counts the chunks harvested: ``symbols`` (all
+        lanes), ``decoded`` (decompress symbols), their CDF ``probes``, and
+        speculation ``hits``: decoded symbols found among the model's top-k
+        candidates, i.e. with at most ``topk`` probes (``core.search``
+        charges a hit at rank r r probes, a miss topk plus its search).
         """
         spec, tables, syms, probes, unders = inflight
-        for rid, s, c, n_c, last in spec:
-            req = self._slots[s]
-            if req is None or req.rid != rid or rid in results:
-                continue        # retired mid-flight (failed upstream chunk)
-            r0, r1 = s * self.lanes, (s + 1) * self.lanes
-            if req.kind == "compress":
-                tbl_c = jax.tree.map(lambda a: a[:n_c, r0:r1], tables)
-                sym_c = jnp.asarray(
-                    req.tokens[:, c * self.chunk_size:
-                               c * self.chunk_size + n_c])
-                enc = _encode_rows(sym_c, tbl_c, cap=req.cap)
-                enc = coder.EncodedLanes(*map(np.asarray, enc))
-                if enc.overflow.any():
-                    cells = np.nonzero(enc.overflow)[0].tolist()
-                    self._retire(req, now, results, error=RequestOverflowError(
-                        f"request {rid}: encode overflow in chunk {c} "
-                        f"(lanes {cells}): the per-request byte budget "
-                        f"(cap={req.cap}) truncated the stream — resubmit "
-                        "with a larger cap"))
-                    continue
-                req.enc_chunks.append(enc)
-            else:
-                und = np.asarray(unders[:n_c, r0:r1])
-                if und.any():
-                    cells = np.nonzero(und.any(axis=0))[0].tolist()
-                    self._retire(req, now, results,
-                                 error=coder.StreamExhaustedError(
-                        f"request {rid}: decode over-read in chunk {c} "
-                        f"(lanes {cells}): a lane's stream ran out of "
-                        "bytes mid-decode — the container is truncated "
-                        "or was produced with a different geometry"))
-                    continue
-                req.out_syms.append(
-                    np.asarray(syms[:n_c, r0:r1]).T.astype(np.int32))
-                req.probes += int(np.asarray(probes[:n_c, r0:r1]).sum())
-            if last:
-                self._retire(req, now, results)
+        span = self.trace.span
+        with span("engine.finalize.cycle") as fin:
+            n_sym = n_dec = n_probes = n_hits = 0
+            for rid, s, c, n_c, last in spec:
+                req = self._slots[s]
+                if req is None or req.rid != rid or rid in results:
+                    continue    # retired mid-flight (failed upstream chunk)
+                r0, r1 = s * self.lanes, (s + 1) * self.lanes
+                if req.kind == "compress":
+                    with span("engine.finalize.encode"):
+                        tbl_c = jax.tree.map(lambda a: a[:n_c, r0:r1],
+                                             tables)
+                        sym_c = jnp.asarray(
+                            req.tokens[:, c * self.chunk_size:
+                                       c * self.chunk_size + n_c])
+                        enc = _encode_rows(sym_c, tbl_c, cap=req.cap)
+                    with span("engine.finalize.wait"):
+                        enc = coder.EncodedLanes(*map(np.asarray, enc))
+                    if enc.overflow.any():
+                        cells = np.nonzero(enc.overflow)[0].tolist()
+                        self._retire(req, now, results,
+                                     error=RequestOverflowError(
+                            f"request {rid}: encode overflow in chunk {c} "
+                            f"(lanes {cells}): the per-request byte budget "
+                            f"(cap={req.cap}) truncated the stream — "
+                            "resubmit with a larger cap"))
+                        continue
+                    req.enc_chunks.append(enc)
+                else:
+                    with span("engine.finalize.wait"):
+                        und = np.asarray(unders[:n_c, r0:r1])
+                    if und.any():
+                        cells = np.nonzero(und.any(axis=0))[0].tolist()
+                        self._retire(req, now, results,
+                                     error=coder.StreamExhaustedError(
+                            f"request {rid}: decode over-read in chunk {c} "
+                            f"(lanes {cells}): a lane's stream ran out of "
+                            "bytes mid-decode — the container is truncated "
+                            "or was produced with a different geometry"))
+                        continue
+                    with span("engine.finalize.wait"):
+                        sym = np.asarray(syms[:n_c, r0:r1])
+                        prb = np.asarray(probes[:n_c, r0:r1])
+                    req.out_syms.append(sym.T.astype(np.int32))
+                    p = int(prb.sum())
+                    req.probes += p
+                    n_dec += prb.size
+                    n_probes += p
+                    n_hits += int((prb <= self.topk).sum())
+                n_sym += n_c * self.lanes
+                if last:
+                    self._retire(req, now, results)
+            fin.attrs.update(symbols=n_sym, decoded=n_dec, probes=n_probes,
+                             hits=n_hits)
 
     def _retire(self, req: _Req, now: float, results: dict,
                 error: Exception | None = None):
         res = RequestResult(rid=req.rid, kind=req.kind, ok=error is None,
                             error=error, n_symbols=req.n_symbols,
                             probes=req.probes, slot=req.slot,
-                            arrival=req.arrival, admitted_at=req.admitted_at,
-                            completed_at=now)
+                            arrival=req.arrival, admitted_at=req.admitted_at)
         if error is None:
-            if req.kind == "compress":
-                ch = jax.tree.map(lambda *xs: np.stack(xs), *req.enc_chunks)
-                res.blob = bitstream.pack_chunked(
-                    ch.buf, ch.start, ch.length, ch.overflow,
-                    chunk_size=self.chunk_size, n_symbols=req.n_symbols,
-                    prob_bits=self.prob_bits)
-            else:
-                res.tokens = np.concatenate(req.out_syms, axis=1)
+            with self.trace.span("engine.retire.pack", rid=req.rid):
+                if req.kind == "compress":
+                    ch = jax.tree.map(lambda *xs: np.stack(xs),
+                                      *req.enc_chunks)
+                    res.blob = bitstream.pack_chunked(
+                        ch.buf, ch.start, ch.length, ch.overflow,
+                        chunk_size=self.chunk_size, n_symbols=req.n_symbols,
+                        prob_bits=self.prob_bits)
+                else:
+                    res.tokens = np.concatenate(req.out_syms, axis=1)
+        # stamped once the result is whole, pack included
+        res.completed_at = (time.perf_counter() - self.trace.t0
+                            if self._wall else now)
+        self.trace.count("requests.retired")
+        if error is not None:
+            self.trace.count("requests.failed")
         results[req.rid] = res
         self._slots[req.slot] = None
 
@@ -725,45 +803,59 @@ class BatchEngine:
         ``clock="virtual"``: time is the cycle counter — fully
         deterministic (the seeded-admission test contract): arrivals are
         in cycle units and the loop jumps idle gaps.  ``clock="wall"``:
-        arrivals are seconds relative to run start; the loop sleeps
-        through idle gaps and stamps real latencies (the bench contract).
+        arrivals are seconds relative to run start (``time.perf_counter``,
+        the trace's clock); the loop sleeps through idle gaps and stamps
+        real latencies (the bench contract).
 
         One cycle: admit -> build inputs (host) -> dispatch (async device)
         -> finalize the PREVIOUS cycle (host pack/encode, blocking only on
         the already-retired launch).  Keeping exactly one cycle in flight
         double-buffers host container work against the device program.
+        Each iteration is one ``engine.cycle`` span, idle sleeps outside.
         """
         if clock not in ("virtual", "wall"):
             raise ValueError(f"unknown clock {clock!r}")
         results: dict[int, RequestResult] = {}
-        t0 = time.monotonic()
         wall = clock == "wall"
+        t0 = self.trace.t0 = time.perf_counter()
+        self._wall = wall
         vnow, cycle = 0.0, 0
         inflight = None
         while self._queue or any(self._slots) or inflight is not None:
-            now = time.monotonic() - t0 if wall else vnow
-            if inflight is not None and self._queue \
-                    and any(last for _, _, _, _, last in inflight[0]):
-                # a retire is pending and successors are waiting: sync the
-                # in-flight cycle NOW so the freed slot refills this cycle
-                # instead of idling one (steady-state chunks keep the
-                # launch-before-finalize double-buffer).
-                self._finalize(inflight, now, results)
-                inflight = None
-            self._admit(now, cycle)
-            built = self._build_cycle()
-            nxt = self._launch(built) if built is not None else None
-            if inflight is not None:
-                now = time.monotonic() - t0 if wall else vnow
-                self._finalize(inflight, now, results)
-            inflight = nxt
+            self.trace.cycle = cycle
+            with self.trace.span("engine.cycle", rids=[]) as cyc:
+                now = time.perf_counter() - t0 if wall else vnow
+                if inflight is not None and self._queue \
+                        and any(last for _, _, _, _, last in inflight[0]):
+                    # a retire is pending and successors are waiting: sync
+                    # the in-flight cycle NOW so the freed slot refills
+                    # this cycle instead of idling one (steady-state chunks
+                    # keep the launch-before-finalize double-buffer).
+                    self._finalize(inflight, now, results)
+                    inflight = None
+                self._admit(now, cycle)
+                built = self._build_cycle()
+                nxt = None
+                if built is not None:
+                    cyc.attrs["rids"] = [rid for rid, *_ in built[0]]
+                    nxt = self._launch(built)
+                if inflight is not None:
+                    now = time.perf_counter() - t0 if wall else vnow
+                    self._finalize(inflight, now, results)
+                inflight = nxt
             if nxt is None and inflight is None and self._queue:
                 gap = min(r.arrival for r in self._queue)
                 if wall:
-                    time.sleep(max(0.0, gap - (time.monotonic() - t0)))
+                    time.sleep(max(0.0, gap - (time.perf_counter() - t0)))
                 else:
                     vnow = max(vnow + 1.0, gap)
             else:
                 vnow += 1.0
             cycle += 1
         return results
+
+
+def _shape_of(a):
+    """A launch argument's shape, dtype, sharding and weak type."""
+    return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding,
+                                weak_type=a.weak_type)

@@ -70,7 +70,7 @@ def test_ragged_join_retire_byte_identity(params):
         assert res[rid].blob == _ref_blob(params, t, 8)
     # continuous batching actually happened: the third request was queued
     # behind a full engine and admitted on a later cycle into a freed slot
-    cycles = {rid: cyc for rid, _slot, cyc in eng.admission_log}
+    cycles = {rid: cyc for rid, _slot, cyc in eng.trace.admissions}
     assert cycles[rids[0]] == 0 and cycles[rids[1]] == 0
     assert cycles[rids[2]] > 0
 
@@ -124,7 +124,7 @@ def test_poisson_admission_determinism(params):
         rids = [eng.submit_compress(t, arrival=float(a))
                 for t, a in zip(toks, arrivals)]
         res = eng.run(clock="virtual")
-        return eng.admission_log, [res[r].blob for r in rids]
+        return list(eng.trace.admissions), [res[r].blob for r in rids]
 
     log1, blobs1 = run_once()
     log2, blobs2 = run_once()
