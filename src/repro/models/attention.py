@@ -300,14 +300,41 @@ def _attend_slots(q: jax.Array, ck: jax.Array, cv: jax.Array,
                                            out.shape[-1])
 
 
+def _ring_write(ring: jax.Array, new: jax.Array, slot: jax.Array,
+                per_row: bool, layer) -> jax.Array:
+    """Write each row's new entry ``new`` (B, KV, Dh) into its slot of the
+    stage's stacked (reps, B, R, KV, Dh) ring, at ``layer`` only.
+
+    §Perf: per-row slots (``per_row``, the serve engine's step program)
+    scatter the B entries into ``[layer, row, slot]`` and write nothing
+    else, in place in the ring the layer and step scans carry.  A scalar
+    position (single-stream ``generate``, ``teacher_forced_scan``, the
+    dry-run ``serve_step``) keeps the masked select over the layer's slots
+    (llama3-405b decode_32k): its cache is sharded on the length axis, and
+    a write at a traced offset on that axis made SPMD gather the whole
+    cache per chip (2x cache temp + reshard); the select stays sharded.
+    Both give the same floats at the same slots.
+    """
+    new = new.astype(ring.dtype)
+    if per_row:
+        rows = jnp.arange(new.shape[0])
+        return ring.at[layer, rows, slot].set(
+            new, indices_are_sorted=True, unique_indices=True)
+    slab = jax.lax.dynamic_index_in_dim(ring, layer, 0, False)
+    hot = (jnp.arange(slab.shape[1]) == slot[0])[None, :, None, None]
+    slab = jnp.where(hot, new[:, None], slab)
+    return jax.lax.dynamic_update_index_in_dim(ring, slab, layer, 0)
+
+
 def attn_decode(p: dict, x1: jax.Array, cache: dict, pos: jax.Array,
                 cfg: ModelConfig, mem: jax.Array | None = None,
-                window: int | None = None):
+                window: int | None = None, layer=None):
     """One-token decode.  x1: (B,1,D); pos: absolute position — a scalar
     int32, or a ``(B,)`` vector of per-row positions (the batched serve
     engine's continuous-batching slots: every row advances its own ring
     independently).  The scalar path is float-identical to the vector path
-    with a constant vector (same broadcasted graph, one row of masks).
+    with a constant vector: the write differs (:func:`_ring_write`), the
+    slot contents and the attend do not.
 
     With ``window`` (or cfg.sliding_window/local_window) and a cache sized
     to the window, indexing is a ring buffer — O(window) memory at 500k+
@@ -315,6 +342,9 @@ def attn_decode(p: dict, x1: jax.Array, cache: dict, pos: jax.Array,
     pos % cache_len; entries older than cache_len are overwritten and
     masked out by age), window or not — the engine's shared-cache wrap
     contract, pinned logit-level in tests/test_serve_engine.py.
+    ``cache`` holds the stage's stacked (reps, B, R, KV, Dh) rings (the
+    layer loop's carry) and this block's are at ``layer``; the returned
+    cache is that stack, written at ``layer`` only.
     Cross-attention decodes against full ``mem`` (no cache).
     """
     if mem is not None:
@@ -330,15 +360,12 @@ def attn_decode(p: dict, x1: jax.Array, cache: dict, pos: jax.Array,
     pos_b = pos_v if pos_v.ndim == 1 else pos_v[None]
     q = apply_rope(q, pos_b[:, None], cfg.rope_theta)
     k = apply_rope(k, pos_b[:, None], cfg.rope_theta)
-    cache_len = cache["k"].shape[1]
+    cache_len = cache["k"].shape[2]
     slot = pos_b % cache_len
-    # §Perf (llama3-405b decode_32k): masked ring write instead of
-    # dynamic_update_slice — elementwise select keeps the context-parallel
-    # cache sharded (DUS at a traced offset forced SPMD to materialize the
-    # full cache per chip: 2x cache temp + reshard).
-    hot = (jnp.arange(cache_len)[None, :] == slot[:, None])[:, :, None, None]
-    ck = jnp.where(hot, k.astype(cache["k"].dtype), cache["k"])
-    cv = jnp.where(hot, v.astype(cache["v"].dtype), cache["v"])
+    ring = {n: _ring_write(cache[n], new[:, 0], slot, pos_v.ndim == 1, layer)
+            for n, new in (("k", k), ("v", v))}
+    ck, cv = (jax.lax.dynamic_index_in_dim(ring[n], layer, 0, False)
+              for n in ("k", "v"))
 
     idx = jnp.arange(cache_len)
     # Unified ring semantics (covers the linear cache too, where slot == pos):
@@ -352,7 +379,7 @@ def attn_decode(p: dict, x1: jax.Array, cache: dict, pos: jax.Array,
         valid &= age < win
     out = _attend_slots(q, ck, cv, valid, cfg)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"])
-    return y, {"k": ck, "v": cv}
+    return y, ring
 
 
 def attn_prefill(p: dict, xs: jax.Array, cache: dict, pos0: jax.Array,

@@ -229,7 +229,9 @@ def recurrent_state_tree(state):
     (``models.transformer.init_block_cache``), and KV rings under
     ``"kv"``.  The engine maps this tree against old/new state to freeze
     inactive rows' recurrent leaves (ring leaves keep the zero-cost
-    clamped-position trick — see ``serve.engine._chunk_body``).
+    clamped-position trick — see ``serve.engine._chunk_body``), and
+    ``models.transformer.stage_decode`` splits a stage's state by it: ring
+    leaves ride the layer scan's carry, recurrent leaves its ``xs``.
     """
     from jax.tree_util import DictKey, tree_map_with_path
 

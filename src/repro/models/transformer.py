@@ -112,11 +112,15 @@ def _ffn(p: dict, x: jax.Array) -> jax.Array:
 
 def block_decode(kind: str, p: dict, x1: jax.Array, cache: dict,
                  pos: jax.Array, cfg: ModelConfig,
-                 mem: jax.Array | None = None):
+                 mem: jax.Array | None = None,
+                 layer: jax.Array | None = None):
+    """One block, one position.  ``cache["kv"]`` is the stage's stacked
+    ring, this block's at ``layer`` (:func:`stage_decode`)."""
     h = _norm(p["ln1"], x1, cfg.norm_eps)
     new_cache = cache
     if kind in ("attn", "attn_moe", "dec"):
-        h, kv = attn_decode(p["attn"], h, cache["kv"], pos, cfg)
+        h, kv = attn_decode(p["attn"], h, cache["kv"], pos, cfg,
+                            layer=layer)
         h = _bar(h)
         new_cache = dict(cache, kv=kv)
     elif kind == "cross":
@@ -229,30 +233,49 @@ def stage_forward(params: dict, x: jax.Array, pattern: tuple[str, ...],
 def stage_decode(params: dict, cache: dict, x1: jax.Array, pos: jax.Array,
                  pattern: tuple[str, ...], cfg: ModelConfig,
                  mem: jax.Array | None = None):
-    def unit(x1, layer_p, layer_c):
+    # §Perf: the KV rings ride the layer loop's carry whole, and each layer
+    # writes its one slot per row in place (attn_decode).  A ring passed as
+    # scan xs -> ys is sliced out and restacked every step, and a step scan
+    # around this one then copies its carry.  Recurrent leaves (ssm/rec),
+    # mutated whole each step, stay per-layer slabs.
+    from repro.models.protocol import recurrent_state_tree
+    leaves, tdef = jax.tree.flatten(cache)
+    is_rec = jax.tree.leaves(recurrent_state_tree(cache))
+
+    def split(ls):
+        return ([a for a, r in zip(ls, is_rec) if not r],
+                [a for a, r in zip(ls, is_rec) if r])
+
+    def join(rings, slabs):
+        rings, slabs = iter(rings), iter(slabs)
+        return tdef.unflatten([next(slabs) if r else next(rings)
+                               for r in is_rec])
+
+    def body(carry, xs):
+        x1, rings = carry
+        layer, layer_p, slabs = xs
+        layer_c = join(rings, slabs)
         new_c = {}
         for i, kind in enumerate(pattern):
             key = f"b{i}_{kind}"
-            x1, c = block_decode(kind, layer_p[key], x1, layer_c[key],
-                                 pos, cfg, mem)
-            new_c[key] = c
-        return x1, new_c
+            x1, new_c[key] = block_decode(kind, layer_p[key], x1,
+                                          layer_c[key], pos, cfg, mem, layer)
+        rings, slabs = split(jax.tree.leaves(new_c))
+        return (x1, rings), slabs
 
-    if cfg.scan_layers:
-        def body(carry, xs):
-            layer_p, layer_c = xs
-            return unit(carry, layer_p, layer_c)
-        x1, new_cache = jax.lax.scan(body, x1, (params, cache))
-        return x1, new_cache
+    rings, slabs = split(leaves)
     reps = jax.tree.leaves(params)[0].shape[0]
+    if cfg.scan_layers:
+        (x1, rings), slabs = jax.lax.scan(
+            body, (x1, rings), (jnp.arange(reps), params, slabs))
+        return x1, join(rings, slabs)
     outs = []
     for r in range(reps):
         layer_p = jax.tree.map(lambda a: a[r], params)
-        layer_c = jax.tree.map(lambda a: a[r], cache)
-        x1, c = unit(x1, layer_p, layer_c)
-        outs.append(c)
-    new_cache = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-    return x1, new_cache
+        (x1, rings), out = body((x1, rings),
+                                (r, layer_p, [a[r] for a in slabs]))
+        outs.append(out)
+    return x1, join(rings, [jnp.stack(z) for z in zip(*outs)])
 
 
 def stage_prefill(params: dict, cache: dict, xs: jax.Array, pos0: jax.Array,
