@@ -95,9 +95,12 @@ def probed_engine(hooks):
 class EngineSystem:
     """One engine configuration under one traffic mix.
 
-    ``model`` is the configuration's module: it gives the program's model
-    config (``model_config``), the weights (``make_weights``) and the plain
-    reference forward (``reference_logits``).
+    ``model`` holds what the configuration's ``build`` hands on: the
+    program's model config (``model_config``), the weights
+    (``make_weights``), the plain reference forward (``reference_logits``)
+    and, where the dense count of ``costs.model_flops`` does not fit the
+    architecture, its own FLOP count (``model_flops``).  Inputs of either kind of mix run as ``(lanes, L)``
+    int32 streams over the model's vocabulary.
     """
 
     def __init__(self, cfg: dict, traffic: dict, seed: int, seconds: float,
@@ -128,35 +131,65 @@ class EngineSystem:
             self.model.model_config(self.cfg), self.seed)
         prog_params = jax.tree.map(
             lambda a: a.astype(self.mcfg.dtype), self.params)
-        imgs = generator.inputs(self.traffic, self.seed)
-        self.tiles = [im.reshape(self.lanes, -1).astype(np.int32)
-                      for im in imgs]
-        t_len = self.tiles[0].shape[1]
+        items = generator.inputs(self.traffic, self.seed,
+                                 self.mcfg.vocab_size, self.lanes)
+        self.streams = [x.reshape(self.lanes, -1).astype(np.int32)
+                        for x in items]
+        t_len = max(x.shape[1] for x in self.streams)
         e = self.cfg["engine"]
-        depth = self.traffic["clients"] + len(self.tiles)
+        depth = self.traffic["clients"] + len(self.streams)
         self.eng = probed_engine(self)(
             prog_params, self.mcfg, slots=e["slots"], lanes=self.lanes,
             chunk_size=self.chunk, max_len=e["max_len"],
             prob_bits=self.prob_bits, topk=e["topk"],
             step_backend=e["step_backend"], prefill=e["prefill"],
-            max_queue=max(64, depth))
+            max_queue=max(64, depth, len(self.streams) * e["slots"]))
         if t_len > e["max_len"]:
             raise ValueError(f"inputs of {t_len} symbols per lane exceed "
                              f"max_len {e['max_len']}")
         self.blobs = None
         if self.kind == "decompress":
-            rids = [self.eng.submit_compress(t) for t in self.tiles]
+            rids = [self.eng.submit_compress(t) for t in self.streams]
             res = self.eng.run(clock="virtual")
             self.blobs = [res[r].blob for r in rids]
             if not all(res[r].ok for r in rids):
                 raise RuntimeError("set-up compress failed")
+        self._warm_ragged()
         jax.block_until_ready(self.eng._cache)
         self.eng.reqs.clear()
         self.results.clear()
 
+    def _warm_ragged(self):
+        """Run, in every slot, each last chunk shorter than ``chunk`` that
+        the inputs end in.  Finalizing such a chunk slices the cycle's
+        outputs, and a compress encodes it, at shapes of their own that no
+        whole chunk compiles; unwarmed, they first come, and compile,
+        inside the window.  One set-up cycle per length (two where the mix
+        decompresses): a request of just that many symbols a lane, under
+        the byte budget that the inputs get, in each slot at once."""
+        from repro.core.coder import default_cap
+        tails = {}
+        for x in self.streams:
+            tails.setdefault(x.shape[1] % self.chunk, x)
+        tails.pop(0, None)
+        if not tails:
+            return
+        rids = []
+        for r, x in sorted(tails.items()):
+            cap = default_cap(min(self.chunk, x.shape[1]))
+            rids += [self.eng.submit_compress(x[:, :r], cap=cap)
+                     for _ in range(self.cfg["engine"]["slots"])]
+        res = self.eng.run(clock="virtual")
+        if self.kind == "decompress":
+            for rid in rids:
+                self.eng.submit_decompress(res[rid].blob)
+            res.update(self.eng.run(clock="virtual"))
+        if not all(r.ok for r in res.values()):
+            raise RuntimeError("set-up of the short last chunks failed")
+
     def _submit(self, item: int):
         if self.kind == "compress":
-            rid = self.eng.submit_compress(self.tiles[item])
+            rid = self.eng.submit_compress(self.streams[item])
         else:
             rid = self.eng.submit_decompress(self.blobs[item])
         self.item_of[rid] = item
@@ -240,7 +273,11 @@ class EngineSystem:
             failed=failed)
 
     def _flops(self) -> float:
-        return float(sum(self.lanes * costs.model_flops(self.cfg, p0, n)
+        """Model FLOPs of the window's finished symbols: the configuration's
+        own ``model_flops(cfg, pos0, n)`` where its module defines one,
+        else the dense count of ``costs.model_flops``."""
+        count = getattr(self.model, "model_flops", costs.model_flops)
+        return float(sum(self.lanes * count(self.cfg, p0, n)
                          for c in self.cycles for p0, n in c["positions"]))
 
     # -- correct -----------------------------------------------------------------
@@ -262,9 +299,9 @@ class EngineSystem:
         if self.kind == "decompress":
             bad = 0
             for rid, req in self.eng.reqs.items():
-                tile = self.tiles[self.item_of[rid]]
+                stream = self.streams[self.item_of[rid]]
                 for c, out in enumerate(req.out_syms):
-                    want = tile[:, c * S:c * S + out.shape[1]]
+                    want = stream[:, c * S:c * S + out.shape[1]]
                     bad += int((out != want).sum()) + abs(
                         want.size - out.size)
             checks.append(common.Check("decode_mismatch", bad, 0))
@@ -286,7 +323,7 @@ class EngineSystem:
                     if (item, key) not in logits:
                         logits[item, key] = np.asarray(
                             self.model.reference_logits(
-                                self.params, self.mcfg, self.tiles[item],
+                                self.params, self.mcfg, self.streams[item],
                                 precision))
                     lg = logits[item, key][:, c * S:c * S + n_c,
                                            :self.mcfg.vocab_size]
@@ -310,9 +347,9 @@ class EngineSystem:
     def _chunk_bytes_gap(self, rid, c, n_c, prog_freq) -> int:
         req = self.eng.reqs[rid]
         enc = req.enc_chunks[c]
-        tile = self.tiles[self.item_of[rid]]
+        stream = self.streams[self.item_of[rid]]
         ref = refcoder.encode_streams(
-            tile[:, c * self.chunk:c * self.chunk + n_c], prog_freq,
+            stream[:, c * self.chunk:c * self.chunk + n_c], prog_freq,
             self.prob_bits)
         gap = 0
         for lane, want in enumerate(ref):

@@ -14,6 +14,7 @@ import sys
 import numpy as np
 import pytest
 
+import generator
 from conftest import BENCH, TINY_CELLS, make_tiny_root, run_tiny
 
 
@@ -86,8 +87,74 @@ def test_new_files_add_a_cell_and_a_metric(tmp_path):
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     plain = run_tiny(root, "wide-ingest")
     assert plain["correct"] and "compress_sym_s" in plain["metrics"]
+    assert plain["_notes"]["counters"]["window.compiles"] == 0
     traced = run_tiny(root, "wide-ingest", trace=True)
     assert traced["metrics"]["cycles_in_window.wide"]["value"] >= 1
+
+
+# the throwaway text model's own FLOP count, 5 a symbol whatever the
+# position, so that the window's total shows which count it took; its
+# build hands the count on beside the parts it shares with tiny-pimc
+TEXT_MODEL = """
+
+def model_flops(cfg, pos0, n):
+    return 5.0 * n
+
+
+def build(cfg, traffic, seed, seconds, probe, dtype=None):
+    import types
+    model = types.SimpleNamespace(model_config=model_config,
+                                  make_weights=make_weights,
+                                  reference_logits=reference_logits,
+                                  model_flops=model_flops)
+    return engine_probe.EngineSystem(cfg, traffic, seed, seconds, probe,
+                                     model, dtype=dtype)
+"""
+
+# request lengths by a quantile law over 4 inputs: 112, 144, 216 and 384
+# ids, 28 to 96 a lane in 4 lanes; three end in a short chunk of a length
+# of its own (12, 4, 6), one in a whole chunk
+TEXT_LAW = {"quantiles": [[0, 96], [0.5, 160], [1, 512]]}
+
+
+@pytest.mark.parametrize("kind", ["compress", "decompress"])
+def test_new_files_add_a_text_cell(tmp_path, kind):
+    """A language-model cell added as new files and entries only: a tiny
+    dense configuration over 512 token ids whose module counts its own
+    FLOPs, and a token-stream mix whose requests' lengths follow a law."""
+    root = make_tiny_root(tmp_path)
+    cfg = json.loads((root / "bench/configs/tiny-pimc.json").read_text())
+    cfg["name"] = "tiny-text"
+    cfg["model"]["vocab_size"] = 512
+    cfg["engine"]["max_len"] = 128
+    (root / "bench/configs/tiny-text.json").write_text(json.dumps(cfg))
+    (root / "bench/configs/tiny-text.py").write_text(
+        (root / "bench/configs/tiny-pimc.py").read_text() + TEXT_MODEL)
+    (root / f"bench/traffic/text-{kind}.json").write_text(json.dumps(dict(
+        loop="closed", kind=kind, clients=3, pool=4,
+        tokens=dict(zipf=1.0, length=TEXT_LAW, source="test"))))
+    cell = f"text-{kind}"
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append(dict(name="tiny-text", source="test",
+                                 file="bench/configs/tiny-text.json",
+                                 reduced=[], why="test"))
+    bench["workloads"].append(dict(name=cell, config="tiny-text",
+                                   traffic=f"text-{kind}", chips=1,
+                                   why="test"))
+    rate = f"{kind}_sym_s"
+    e2e = {m["name"]: m for m in bench["end_to_end"]}
+    e2e[rate]["workloads"].append(cell)
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    assert [n // 4 % 16 for n in generator.lengths(TEXT_LAW, 4, 4)] \
+        == [12, 4, 6, 0]
+    r = run_tiny(root, cell, seconds=2.0)
+    assert r["correct"], r["checks"]
+    assert set(r["metrics"]) == {rate, "setup_s"}
+    counters = r["_notes"]["counters"]
+    # the short last chunks ran in set-up: nothing compiles in the window
+    assert counters["window.compiles"] == 0
+    assert counters["window.symbols"] > 0
+    assert counters["window.model_flops"] == 5.0 * counters["window.symbols"]
 
 
 def test_off_the_chip_the_command_exits_nonzero():
